@@ -89,10 +89,10 @@ def run():
     for name, kw in variants.items():
         letter = name[0]
         if kw.get("use_kernel"):
-            # interpret-mode: correctness only; time the REF with the same
-            # conversion policy for a consistent CPU wall number
+            # the kernel is checked for correctness only; time the REF with
+            # the same conversion policy for a consistent CPU wall number
             out_k = ops.scan_scores(q[:8], db[:1024], ids[:1024], None,
-                                    metric="ip", interpret=True, **kw)
+                                    metric="ip", **kw)
             out_r = ops.scan_scores(
                 q[:8], db[:1024], ids[:1024], None, metric="ip",
                 use_kernel=False,

@@ -22,10 +22,6 @@ import dataclasses
 import os
 import time
 
-# the sharded-maintenance lane wants a (tiny) real mesh; only effective when
-# this process initializes jax itself (harmless otherwise — the lane skips)
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=2")
-
 import numpy as np
 
 from benchmarks import common
@@ -940,6 +936,10 @@ def smoke():
 
 
 if __name__ == "__main__":
+    # the sharded lanes want a (tiny) real mesh; on a CPU-only host ask for
+    # two devices, which takes effect before this process starts JAX
+    os.environ.setdefault("XLA_FLAGS",
+                          "--xla_force_host_platform_device_count=2")
     args = argparse.ArgumentParser()
     args.add_argument("--smoke", action="store_true",
                       help="tiny quantized lane only (CI)")

@@ -15,12 +15,14 @@ import time
 import traceback
 
 from benchmarks import common
+from repro import compile_cache
 
 
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None)
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     from benchmarks import (bench_ablation, bench_cluster_sweep,
                             bench_gemm_heatmap, bench_hybrid,

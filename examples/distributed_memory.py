@@ -5,7 +5,9 @@
 The paper's engine is single-device.  This example runs the distributed
 tier through the same multi-tenant API as the on-device one: a collection
 created with `shard_db=True` and a mesh shards its IVF lists row-wise over
-8 virtual host devices, each shard scans locally with the fused-GEMM path,
+every device the process sees (on a CPU, ask for several with
+`XLA_FLAGS=--xla_force_host_platform_device_count=8`), each shard scans
+locally with the fused-GEMM path,
 and candidates merge into a global top-k — a billion-vector memory behind
 the same `MemoryService` calls.  Includes distributed insert routing,
 cross-collection fused batched queries over sharded tenants (one shard_map
@@ -13,9 +15,6 @@ dispatch for G tenants), shard-local deletes + rebuild (one shard
 compacted, siblings untouched — see docs/ARCHITECTURE.md), and sharded
 save/load.
 """
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-
 import jax
 import numpy as np
 
@@ -25,7 +24,7 @@ from repro.core import metrics
 
 
 def main():
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = jax.make_mesh((jax.device_count(),), ("shard",))
     cfg = EngineConfig(dim=128, n_clusters=128, list_capacity=64,
                        nprobe=16, k=5, use_kernel=False, kmeans_iters=4,
                        shard_db=True)
@@ -40,7 +39,7 @@ def main():
     svc.build("planet", x, ids=ids)
     print(f"distributed build ok: lists sharded over "
           f"{mesh.devices.size} devices "
-          f"(per-device rows ~ {cfg.capacity // 8})")
+          f"(per-device rows ~ {cfg.capacity // mesh.devices.size})")
 
     q = x[:8] + 0.02 * rng.standard_normal((8, cfg.dim), dtype=np.float32)
     got_ids, scores = svc.query("planet", q, k=5)

@@ -353,7 +353,7 @@ class Collection:
                     host = self._read_cold_host()
                 if self.sharded:
                     from repro.core import distributed as dce
-                    state = dce.assemble_host(host)
+                    state = dce.assemble_host(host, self.mesh)
                 else:
                     state = jax.tree.map(jnp.asarray, host)
                 with self._lock:
@@ -706,7 +706,10 @@ class Collection:
         computed off-lock and swapped unconditionally — the same lost-update
         race rebuild had).  Queries keep reading the old snapshot throughout.
         """
-        x = jnp.asarray(vectors, jnp.float32)
+        # sharded rows stay on the host until dist_build places each
+        # device's block there (never the whole batch on the first device)
+        x = (np.asarray(vectors, np.float32) if self.sharded
+             else jnp.asarray(vectors, jnp.float32))
         self._check_shardable("build", int(x.shape[0]))
         ids = self._ids_for(x.shape[0], ids)
         t0 = time.perf_counter()
@@ -1164,7 +1167,7 @@ class Collection:
                 q_spill_norms=grow_dst(d.q_spill_norms,
                                        np.asarray(s.q_spill_norms)[take]))
         shards[src], shards[dst] = s_new, d_new
-        return dce.assemble_host(shards), m, dst
+        return dce.assemble_host(shards, self.mesh), m, dst
 
     # ------------------------------------------------------------------
     # Index policy + derived HNSW graph tier (recall-adaptive routing)
@@ -1677,7 +1680,7 @@ class Collection:
                         coll._host_state = shards
                         coll._residency_tier = "warm"
                 else:
-                    coll.state = dce.assemble_host(shards)
+                    coll.state = dce.assemble_host(shards, coll.mesh)
         else:
             if residency == "cold":
                 with coll._lock:

@@ -735,6 +735,9 @@ class MemoryService:
                   residency_dir=residency_dir, idle_demote_s=idle_demote_s,
                   cold_after_s=cold_after_s)
         for name, entry in registry["collections"].items():
+            # registries written before kernel mode followed the platform
+            # carry an `interpret` field; it no longer configures anything
+            entry["cfg"].pop("interpret", None)
             cfg = EngineConfig(**entry["cfg"])
             kw = {}
             if entry.get("sharded", cfg.shard_db):

@@ -223,7 +223,6 @@ class EngineConfig:
     aligned: bool = True             # tile-aligned cluster count / padding
     fused_conversion: bool = True    # fp32->bf16 inside the kernel (vs pre-copy)
     use_kernel: bool = True          # pallas kernels vs pure-jnp reference
-    interpret: bool = True           # CPU container: run kernels in interpret mode
 
     # scheduler
     window: int = 8                  # windowed batch submission size

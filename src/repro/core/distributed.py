@@ -49,9 +49,8 @@ from typing import List, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
-
-from repro.core.compat import shard_map
+from jax import shard_map
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import EngineConfig
 from repro.core import index as ivf
@@ -109,12 +108,23 @@ def state_specs(mesh: Mesh, quantized: bool = False) -> ivf.IVFState:
     return specs
 
 
+def state_shardings(mesh: Mesh, quantized: bool = False) -> ivf.IVFState:
+    """`state_specs` as `NamedSharding`s on `mesh` (placement targets)."""
+    return jax.tree.map(lambda sp: NamedSharding(mesh, sp),
+                        state_specs(mesh, quantized))
+
+
 def empty_dist_state(cfg: EngineConfig, mesh: Mesh,
                      spill_capacity_per_shard: int = 4096) -> ivf.IVFState:
-    """Global arrays for the sharded state (local view == IVFState)."""
-    s = mesh.size
+    """Global arrays for the sharded state (local view == IVFState), each
+    device allocating only its own slab."""
+    return jax.jit(functools.partial(_empty_dist_state, cfg, mesh.size,
+                                     spill_capacity_per_shard),
+                   out_shardings=state_shardings(mesh, cfg.quantized))()
+
+
+def _empty_dist_state(cfg: EngineConfig, s: int, sc: int) -> ivf.IVFState:
     c, l, d = cfg.n_clusters, cfg.list_capacity, cfg.dim
-    sc = spill_capacity_per_shard
     st = ivf.IVFState(
         centroids=jnp.zeros((c, d), jnp.float32),
         lists=jnp.zeros((c, l * s, d), jnp.float32),
@@ -180,11 +190,11 @@ def dist_build(key, x, ids, cfg: EngineConfig, mesh: Mesh,
         def step(cent, key_i):
             idx, _ = ops.kmeans_assign(
                 x_loc, cent, use_kernel=cfg.use_kernel,
-                fused_conversion=cfg.fused_conversion, interpret=cfg.interpret)
+                fused_conversion=cfg.fused_conversion)
             idx = jnp.where(valid, idx, -1)
             sums, counts = ops.segsum_gemm(
                 x_loc, idx, n_clusters=cfg.n_clusters,
-                use_kernel=cfg.use_kernel, interpret=cfg.interpret)
+                use_kernel=cfg.use_kernel)
             sums = jax.lax.psum(sums, ax)        # O(C*D) collective
             counts = jax.lax.psum(counts, ax)
             new = sums / jnp.maximum(counts, 1.0)[:, None]
@@ -200,7 +210,7 @@ def dist_build(key, x, ids, cfg: EngineConfig, mesh: Mesh,
         # ---- local pack into this shard's slots ----
         idx, _ = ops.kmeans_assign(
             x_loc, centroids, use_kernel=cfg.use_kernel,
-            fused_conversion=cfg.fused_conversion, interpret=cfg.interpret)
+            fused_conversion=cfg.fused_conversion)
         idx = jnp.where(valid, idx, -1)
         st = ivf.empty_state(cfg, spill_capacity_per_shard)
         st = st._replace(centroids=centroids)
@@ -216,7 +226,8 @@ def dist_build(key, x, ids, cfg: EngineConfig, mesh: Mesh,
     )
     base = int(jax.random.randint(key, (), 0, 2**31 - 1))
     seeds = (base + jnp.arange(mesh.size, dtype=jnp.int32)) % (2**31 - 1)
-    return fn(seeds, x, ids)
+    rows = NamedSharding(mesh, P(ax))
+    return fn(seeds, jax.device_put(x, rows), jax.device_put(ids, rows))
 
 
 # ---------------------------------------------------------------------------
@@ -447,7 +458,7 @@ def _rebuild_fn(mesh: Mesh, cfg: EngineConfig):
             rows, ids = ivf._flat_rows(st)
             idx, _ = ops.kmeans_assign(
                 rows, st.centroids, use_kernel=cfg.use_kernel,
-                fused_conversion=cfg.fused_conversion, interpret=cfg.interpret)
+                fused_conversion=cfg.fused_conversion)
             idx = jnp.where(ids >= 0, idx, -1)
             fresh = ivf.empty_state(cfg, st.spill.shape[0])._replace(
                 centroids=st.centroids)
@@ -574,18 +585,22 @@ def dist_replay(state: ivf.IVFState, log: Sequence[ivf.DeltaOp], shard: int,
     """
     ins_fn, del_fn = _replay_fns(mesh, cfg)
     shard_t = jnp.asarray([shard], jnp.int32)
-    spilled = jnp.zeros((), jnp.int32)
-    tombstoned = jnp.zeros((), jnp.int32)
+    spilled, tombstoned = [], []
     for op in log:
         if op.kind == "insert":
             state, sp = ins_fn(state, shard_t, op.rows, op.ids)
-            spilled = spilled + sp[shard]
+            spilled.append(sp)
         elif op.kind == "delete":
             state, n = del_fn(state, shard_t, op.ids)
-            tombstoned = tombstoned + n[shard]
+            tombstoned.append(n)
         else:
             raise ValueError(f"unknown delta op kind {op.kind!r}")
-    return state, int(spilled), int(tombstoned)
+    # The per-shard counts are sharded over the mesh; indexing one entry on
+    # the device is a gather JAX will not place on an Explicit mesh axis, so
+    # read them back in one transfer and pick this shard's entry on the host.
+    spilled, tombstoned = jax.device_get((spilled, tombstoned))
+    return (state, sum(int(sp[shard]) for sp in spilled),
+            sum(int(n[shard]) for n in tombstoned))
 
 
 # ---------------------------------------------------------------------------
@@ -630,34 +645,28 @@ def split_host(state: ivf.IVFState, n_shards: int) -> List[ivf.IVFState]:
     return out
 
 
-def assemble_host(shards: Sequence[ivf.IVFState]) -> ivf.IVFState:
-    """Per-shard local states -> global arrays in `state_specs` layout.
+def assemble_host(shards: Sequence[ivf.IVFState],
+                  mesh: Mesh) -> ivf.IVFState:
+    """Per-shard local states -> the global state in `state_specs` layout,
+    placed on `mesh`: device `i` receives shard `i`'s slab only (never the
+    whole state on the first device)."""
+    def cat(name, axis):
+        return np.concatenate([np.asarray(getattr(s, name)) for s in shards],
+                              axis=axis)
 
-    The result is uncommitted (no device placement); the first `shard_map`
-    dispatch reshards it onto the mesh.
-    """
+    def stack(name):
+        return np.stack([np.asarray(getattr(s, name)).reshape(())
+                         for s in shards])
+
     st = ivf.IVFState(
-        centroids=jnp.asarray(shards[0].centroids),
-        lists=jnp.asarray(np.concatenate([np.asarray(s.lists) for s in shards],
-                                         axis=1)),
-        list_ids=jnp.asarray(np.concatenate(
-            [np.asarray(s.list_ids) for s in shards], axis=1)),
-        list_sizes=jnp.asarray(np.concatenate(
-            [np.asarray(s.list_sizes) for s in shards], axis=0)),
-        spill=jnp.asarray(np.concatenate([np.asarray(s.spill) for s in shards],
-                                         axis=0)),
-        spill_ids=jnp.asarray(np.concatenate(
-            [np.asarray(s.spill_ids) for s in shards], axis=0)),
-        spill_size=jnp.asarray(np.stack(
-            [np.asarray(s.spill_size).reshape(()) for s in shards])),
-        num_deleted=jnp.asarray(np.stack(
-            [np.asarray(s.num_deleted).reshape(()) for s in shards])),
+        centroids=np.asarray(shards[0].centroids),
+        lists=cat("lists", 1), list_ids=cat("list_ids", 1),
+        list_sizes=cat("list_sizes", 0), spill=cat("spill", 0),
+        spill_ids=cat("spill_ids", 0), spill_size=stack("spill_size"),
+        num_deleted=stack("num_deleted"),
     )
-    if shards[0].q_lists is not None:
-        def cat(name, axis):
-            return jnp.asarray(np.concatenate(
-                [np.asarray(getattr(s, name)) for s in shards], axis=axis))
-
+    quantized = shards[0].q_lists is not None
+    if quantized:
         st = st._replace(
             q_lists=cat("q_lists", 1), q_scales=cat("q_scales", 0),
             q_zeros=cat("q_zeros", 0), q_norms=cat("q_norms", 1),
@@ -665,7 +674,7 @@ def assemble_host(shards: Sequence[ivf.IVFState]) -> ivf.IVFState:
             q_spill_zeros=cat("q_spill_zeros", 0),
             q_spill_norms=cat("q_spill_norms", 0),
         )
-    return st
+    return jax.device_put(st, state_shardings(mesh, quantized))
 
 
 def reshard_host(shards: Sequence[ivf.IVFState], cfg: EngineConfig,

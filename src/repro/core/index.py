@@ -345,7 +345,7 @@ def _insert(state: IVFState, x: jax.Array, ids: jax.Array,
     l_cap = state.list_capacity
     cl, _ = ops.kmeans_assign(
         x, state.centroids, use_kernel=cfg.use_kernel,
-        fused_conversion=cfg.fused_conversion, interpret=cfg.interpret)
+        fused_conversion=cfg.fused_conversion)
 
     rank = _batch_ranks(cl)
     offsets = state.list_sizes[cl] + rank
@@ -585,7 +585,7 @@ def _query_full_scan_q8(state: IVFState, q: jax.Array, cfg: EngineConfig,
     coarse = ops.scan_scores_q8(
         q, codes, ids, scales, zeros,
         norms if cfg.metric == "l2" else None, metric=cfg.metric,
-        use_kernel=cfg.use_kernel, interpret=cfg.interpret)
+        use_kernel=cfg.use_kernel)
     r = _rescore_r(state, cfg, k, codes.shape[0])
     _, cand = jax.lax.top_k(_order_scores(coarse, cfg.metric), r)
     rows = _gather_flat_rows(state, cand)
@@ -610,8 +610,7 @@ def query_full_scan(state: IVFState, q: jax.Array, cfg: EngineConfig,
     rows, ids = _flat_rows(state)
     scores = ops.scan_scores(
         q, rows, ids, _metric_norms(rows, cfg.metric), metric=cfg.metric,
-        use_kernel=cfg.use_kernel, fused_conversion=cfg.fused_conversion,
-        interpret=cfg.interpret)
+        use_kernel=cfg.use_kernel, fused_conversion=cfg.fused_conversion)
     top, idx = jax.lax.top_k(_order_scores(scores, cfg.metric), k)
     return ids[idx], top
 
@@ -626,8 +625,7 @@ def query_full_scan_rows(state: IVFState, q: jax.Array, cfg: EngineConfig,
     rows, ids = _flat_rows(state)
     scores = ops.scan_scores(
         q, rows, ids, _metric_norms(rows, cfg.metric), metric=cfg.metric,
-        use_kernel=cfg.use_kernel, fused_conversion=cfg.fused_conversion,
-        interpret=cfg.interpret)
+        use_kernel=cfg.use_kernel, fused_conversion=cfg.fused_conversion)
     top, idx = jax.lax.top_k(_order_scores(scores, cfg.metric), k)
     return ids[idx], top, rows[idx]
 
@@ -650,7 +648,7 @@ def query_probed(state: IVFState, q: jax.Array, cfg: EngineConfig,
     cscores = ops.scan_scores(
         q, state.centroids, cvalid, _metric_norms(state.centroids, cfg.metric),
         metric=cfg.metric, use_kernel=cfg.use_kernel,
-        fused_conversion=cfg.fused_conversion, interpret=cfg.interpret)
+        fused_conversion=cfg.fused_conversion)
     _, probes = jax.lax.top_k(_order_scores(cscores, cfg.metric), nprobe)
 
     spill_rows, spill_ids = state.spill, state.spill_ids
@@ -674,7 +672,7 @@ def query_probed(state: IVFState, q: jax.Array, cfg: EngineConfig,
             s = ops.scan_scores_q8(
                 qi[None], codes, rids, scales, zeros,
                 norms if cfg.metric == "l2" else None, metric=cfg.metric,
-                use_kernel=cfg.use_kernel, interpret=cfg.interpret)
+                use_kernel=cfg.use_kernel)
             r = _rescore_r(state, cfg, k, codes.shape[0])
             _, cand = jax.lax.top_k(_order_scores(s, cfg.metric), r)
             # survivor f32 rows: probed-slab indices map through pi
@@ -692,7 +690,7 @@ def query_probed(state: IVFState, q: jax.Array, cfg: EngineConfig,
         s = ops.scan_scores(
             qi[None], rows, rids, _metric_norms(rows, cfg.metric),
             metric=cfg.metric, use_kernel=cfg.use_kernel,
-            fused_conversion=cfg.fused_conversion, interpret=cfg.interpret)
+            fused_conversion=cfg.fused_conversion)
         top, idx = jax.lax.top_k(_order_scores(s, cfg.metric)[0], k)
         return rids[idx], top
 

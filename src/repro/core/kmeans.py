@@ -41,11 +41,10 @@ def kmeans(key: jax.Array, x: jax.Array, valid: jax.Array,
         cent = carry
         idx, _ = ops.kmeans_assign(
             x, cent, use_kernel=cfg.use_kernel,
-            fused_conversion=cfg.fused_conversion, interpret=cfg.interpret)
+            fused_conversion=cfg.fused_conversion)
         idx = jnp.where(valid, idx, -1)
         sums, counts = ops.segsum_gemm(
-            x, idx, n_clusters=c, use_kernel=cfg.use_kernel,
-            interpret=cfg.interpret)
+            x, idx, n_clusters=c, use_kernel=cfg.use_kernel)
         new = sums / jnp.maximum(counts, 1.0)[:, None]
         # re-seed empty clusters from random valid rows
         g = jax.random.gumbel(key_i, (m,)) + jnp.where(valid, 0.0, -1e30)
@@ -62,5 +61,5 @@ def kmeans(key: jax.Array, x: jax.Array, valid: jax.Array,
 
     final_idx, _ = ops.kmeans_assign(
         x, centroids, use_kernel=cfg.use_kernel,
-        fused_conversion=cfg.fused_conversion, interpret=cfg.interpret)
+        fused_conversion=cfg.fused_conversion)
     return centroids, jnp.where(valid, final_idx, -1)

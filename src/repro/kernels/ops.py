@@ -4,6 +4,9 @@ These handle (a) padding arbitrary shapes up to kernel block multiples — the
 paper's M-dimension round-up to the tile size, (b) the kernel/ref dispatch
 driven by ``EngineConfig`` ablation flags, and (c) the un-fused baseline that
 materializes a converted copy (the "naive port" the paper argues against).
+
+Whether a kernel is compiled or interpreted follows from the platform alone
+(`interpret_kernels`): compiled on a TPU, Pallas interpret mode elsewhere.
 """
 from __future__ import annotations
 
@@ -20,6 +23,12 @@ from repro.kernels import segsum_gemm as _segsum
 NEG_INF = float("-inf")
 
 
+def interpret_kernels() -> bool:
+    """True unless the default backend is a TPU, the one platform the
+    kernels compile for; everywhere else Pallas interprets them."""
+    return jax.default_backend() != "tpu"
+
+
 def _pad_to(x: jax.Array, axis: int, mult: int, value=0):
     n = x.shape[axis]
     target = ((n + mult - 1) // mult) * mult
@@ -31,11 +40,9 @@ def _pad_to(x: jax.Array, axis: int, mult: int, value=0):
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "metric", "use_kernel", "fused_conversion", "interpret",
-    "block_m", "block_n", "block_k"))
+    "metric", "use_kernel", "fused_conversion", "block_m", "block_n", "block_k"))
 def scan_scores(q, db, ids, db_norms=None, *, metric="ip", use_kernel=True,
-                fused_conversion=True, interpret=True,
-                block_m=128, block_n=512, block_k=512):
+                fused_conversion=True, block_m=128, block_n=512, block_k=512):
     """Similarity scores fp32[B, N] between queries and database rows.
 
     Pads B/N/D to block multiples; padded DB rows get id -1 (masked -inf),
@@ -60,15 +67,14 @@ def scan_scores(q, db, ids, db_norms=None, *, metric="ip", use_kernel=True,
     out = _scan.scan_scores(
         qp.astype(jnp.float32), dbp.astype(jnp.float32), idsp, db_norms,
         metric=metric, block_m=block_m, block_n=block_n, block_k=block_k,
-        fused_conversion=fused_conversion, interpret=interpret)
+        fused_conversion=fused_conversion, interpret=interpret_kernels())
     return out[:b, :n]
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "metric", "use_kernel", "interpret", "block_m", "block_n", "block_k"))
+    "metric", "use_kernel", "block_m", "block_n", "block_k"))
 def scan_scores_q8(q, codes, ids, scales, zeros, db_norms=None, *,
-                   metric="ip", use_kernel=True, interpret=True,
-                   block_m=128, block_n=512, block_k=512):
+                   metric="ip", use_kernel=True, block_m=128, block_n=512, block_k=512):
     """Quantized coarse scan: fp32[B, N] approximate scores.
 
     q is fp32[B, D]; it is quantized here (symmetric per-query int8, see
@@ -97,15 +103,14 @@ def scan_scores_q8(q, codes, ids, scales, zeros, db_norms=None, *,
     out = _scan.scan_scores_q8(
         qp, cp, idsp, scalesp, zerosp, sqp, corrp, db_norms,
         metric=metric, block_m=block_m, block_n=block_n, block_k=block_k,
-        interpret=interpret)
+        interpret=interpret_kernels())
     return out[:b, :n]
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "use_kernel", "fused_conversion", "interpret", "block_m", "block_c",
-    "block_k"))
+    "use_kernel", "fused_conversion", "block_m", "block_c", "block_k"))
 def kmeans_assign(x, centroids, *, use_kernel=True, fused_conversion=True,
-                  interpret=True, block_m=256, block_c=256, block_k=512):
+                  block_m=256, block_c=256, block_k=512):
     """(idx int32[M], dist fp32[M]) nearest centroid per row (L2, mod ||x||^2)."""
     if not use_kernel:
         return _ref.kmeans_assign_ref(x, centroids,
@@ -117,14 +122,13 @@ def kmeans_assign(x, centroids, *, use_kernel=True, fused_conversion=True,
     idx, dist = _assign.kmeans_assign(
         xp.astype(jnp.float32), cp.astype(jnp.float32),
         block_m=block_m, block_c=block_c, block_k=block_k,
-        fused_conversion=fused_conversion, interpret=interpret)
+        fused_conversion=fused_conversion, interpret=interpret_kernels())
     return jnp.minimum(idx[:m], c - 1), dist[:m]
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "n_clusters", "use_kernel", "interpret", "block_m", "block_c", "block_d"))
-def segsum_gemm(x, assign, *, n_clusters, use_kernel=True, interpret=True,
-                block_m=512, block_c=128, block_d=512):
+    "n_clusters", "use_kernel", "block_m", "block_c", "block_d"))
+def segsum_gemm(x, assign, *, n_clusters, use_kernel=True, block_m=512, block_c=128, block_d=512):
     """(sums fp32[C, D], counts fp32[C]); assign < 0 rows are ignored."""
     if not use_kernel:
         # one_hot(-1) is all-zeros, so negative assignments drop out naturally
@@ -136,5 +140,5 @@ def segsum_gemm(x, assign, *, n_clusters, use_kernel=True, interpret=True,
     sums, counts = _segsum.segsum_gemm(
         xp.astype(jnp.float32), ap, n_clusters=c_pad,
         block_m=block_m, block_c=block_c, block_d=block_d,
-        interpret=interpret)
+        interpret=interpret_kernels())
     return sums[:n_clusters, : x.shape[1]], counts[:n_clusters]

@@ -1,9 +1,20 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+"""512-device multi-pod dry-run: lower + compile every (arch x shape x mesh)
+cell and extract memory / cost / collective evidence for the roofline.
 
-# --- everything below may touch jax; the two lines above MUST run first ----
+This is the proof of large-scale runnability required by the spec: a cell
+that fails to lower (sharding mismatch), fails to compile (unsupported
+collective), or does not fit per-device HBM (memory_analysis) is a bug in
+the system, not in the methodology.
+
+All recorded HLO-derived numbers are PER DEVICE (the partitioned module's
+shapes are shard shapes); roofline terms follow directly (launch/roofline.py).
+
+The 512 host devices are requested in `main`, not at import, so importing
+this module (the lowering tests do) never changes a process's device count.
+"""
 import argparse
 import json
+import os
 import time
 import traceback
 from typing import Any, Dict, Optional, Tuple
@@ -20,18 +31,6 @@ from repro.models import api, lm, specs
 from repro.models.sharding import use_mesh
 from repro.train import optimizer
 from repro.train.train_step import make_train_step
-
-"""512-device multi-pod dry-run: lower + compile every (arch x shape x mesh)
-cell and extract memory / cost / collective evidence for the roofline.
-
-This is the proof of large-scale runnability required by the spec: a cell
-that fails to lower (sharding mismatch), fails to compile (unsupported
-collective), or does not fit per-device HBM (memory_analysis) is a bug in
-the system, not in the methodology.
-
-All recorded HLO-derived numbers are PER DEVICE (the partitioned module's
-shapes are shard shapes); roofline terms follow directly (launch/roofline.py).
-"""
 
 
 # ---------------------------------------------------------------------------
@@ -292,6 +291,8 @@ def main(argv=None):
     ap.add_argument("--remat", default="block")
     ap.add_argument("--print-memory", action="store_true")
     args = ap.parse_args(argv)
+    # takes effect only before this process first initializes a backend
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
     archs = [args.arch] if args.arch else registry.list_archs()
     shapes = [args.shape] if args.shape else list(

@@ -7,19 +7,26 @@ init, and smoke tests / benches must keep seeing 1 CPU device.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 from repro.configs.base import MeshConfig
+
+
+def _auto_mesh(shape, axes):
+    # the model stack shards by GSPMD constraints (models/sharding.py),
+    # which take Auto axes; jax.make_mesh defaults to Explicit ones
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 ('data','model') single-pod, or 2x16x16 ('pod','data','model')."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_mesh(mc: MeshConfig):
-    return jax.make_mesh(mc.shape, mc.axes)
+    return _auto_mesh(mc.shape, mc.axes)
 
 
 def describe(mesh) -> str:

@@ -8,10 +8,8 @@ multipliers applied — the dry-run analogue of a wall-clock trace viewer.
     PYTHONPATH=src python -m repro.launch.profile --arch deepseek-moe-16b \
         --shape train_4k [--multi-pod] [--what collectives|hbm] [--top 15]
 """
-import os
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=512")
-
 import argparse
+import os
 import re
 from collections import defaultdict
 
@@ -77,6 +75,9 @@ def main(argv=None):
                     choices=("collectives", "hbm"))
     ap.add_argument("--top", type=int, default=15)
     args = ap.parse_args(argv)
+    # takes effect only before this process first initializes a backend
+    os.environ.setdefault("XLA_FLAGS",
+                          "--xla_force_host_platform_device_count=512")
 
     from repro.configs import registry
     from repro.launch import dryrun

@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import compile_cache
 from repro.api import MemoryOp, MemoryService
 from repro.configs import registry
 from repro.configs.base import EngineConfig
@@ -41,13 +42,14 @@ def main(argv=None):
     ap.add_argument("--production-mesh", action="store_true")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     cfg = registry.reduced_arch(args.arch)
     if cfg.family == "encdec":
         raise SystemExit("serve driver targets decoder LMs; use examples/"
                          "quickstart.py for the enc-dec path")
     ecfg = EngineConfig(dim=cfg.d_model, n_clusters=128, list_capacity=64,
-                        nprobe=16, k=args.mem_k, interpret=True)
+                        nprobe=16, k=args.mem_k)
     mesh = make_production_mesh() if args.production_mesh else None
 
     key = jax.random.PRNGKey(args.seed)
@@ -99,7 +101,8 @@ def main(argv=None):
     n_tok = args.requests * args.decode_steps
     print(f"retrieved memory ids (req 0): {np.asarray(mem_ids)[0].tolist()}")
     print(f"generated {n_tok} tokens in {t2 - t1:.2f}s "
-          f"({n_tok / (t2 - t1):.1f} tok/s, CPU interpret mode)")
+          f"({n_tok / (t2 - t1):.1f} tok/s on "
+          f"{jax.devices()[0].device_kind})")
     print(f"memory stats: {memory.stats()}")
     print(f"scheduler: {sched.stats()}")
 

@@ -28,10 +28,10 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
-from repro.core.compat import shard_map
 from repro.models import layers
 from repro.models.sharding import current_mesh, shard
 

@@ -18,7 +18,7 @@ import threading
 from typing import Optional
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 _state = threading.local()
 
@@ -97,8 +97,11 @@ def shard(x: jax.Array, *logical: Optional[str]) -> jax.Array:
             used.update(ax)
         else:
             out.append(None)
-    return jax.lax.with_sharding_constraint(
-        x, NamedSharding(mesh, P(*out)))
+    # a GSPMD constraint names Auto axes; a mesh from jax.make_mesh has
+    # Explicit ones, so constrain on the same devices viewed as Auto
+    auto = Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
+    return jax.lax.with_sharding_constraint(x, NamedSharding(auto, P(*out)))
 
 
 def named_sharding(*logical: Optional[str]) -> Optional[NamedSharding]:

@@ -194,6 +194,28 @@ def test_service_save_load_roundtrip(tmp_path, service):
         svc2.shutdown()
 
 
+def test_load_registry_with_retired_interpret_field(tmp_path):
+    """A registry saved while `EngineConfig` still had `interpret` loads,
+    and the field carries nothing into the restored config."""
+    import json
+    svc = MemoryService(maintenance=False)
+    svc.create_collection("old", CFG)
+    x = _corpus(400, seed=7)
+    svc.build("old", x)
+    svc.save(str(tmp_path))
+    svc.shutdown()
+    path = tmp_path / "service.json"
+    registry = json.loads(path.read_text())
+    registry["collections"]["old"]["cfg"]["interpret"] = True
+    path.write_text(json.dumps(registry))
+    back = MemoryService.load(str(tmp_path), maintenance=False)
+    try:
+        assert back.collection("old").cfg == CFG
+        assert back.collection("old").stats()["live"] == 400
+    finally:
+        back.shutdown()
+
+
 def test_atomic_metadata_write(tmp_path):
     """collection.json lands via os.replace: no partial file ever visible."""
     coll = Collection("solo", CFG)

@@ -31,7 +31,7 @@ if jax.device_count() >= 8:
     from repro.core import metrics
 
     CFG = EngineConfig(dim=128, n_clusters=128, list_capacity=32, nprobe=8,
-                       k=10, kmeans_iters=3, interpret=True)
+                       k=10, kmeans_iters=3)
 
 
 @pytest.fixture(scope="module")

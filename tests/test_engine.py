@@ -10,7 +10,7 @@ from repro.core import metrics
 from repro.core.engine import AgenticMemoryEngine
 
 CFG = EngineConfig(dim=128, n_clusters=128, list_capacity=64, nprobe=16,
-                   k=10, kmeans_iters=4, interpret=True)
+                   k=10, kmeans_iters=4)
 
 
 def corpus(n=2000, d=128, n_centers=32, seed=0):
@@ -89,7 +89,7 @@ def test_delete_tombstones_then_rebuild_reclaims():
 def test_spill_overflow_and_rebuild_drain():
     # tiny lists force spill
     cfg = EngineConfig(dim=128, n_clusters=128, list_capacity=8, nprobe=16,
-                       k=5, kmeans_iters=2, interpret=True)
+                       k=5, kmeans_iters=2)
     eng = AgenticMemoryEngine(cfg, spill_capacity=8192)
     x = corpus(3000)
     eng.build(x)
@@ -103,7 +103,7 @@ def test_spill_overflow_and_rebuild_drain():
 
 def test_l2_metric_route():
     cfg = EngineConfig(dim=128, n_clusters=128, list_capacity=64, nprobe=16,
-                       k=5, metric="l2", kmeans_iters=3, interpret=True)
+                       k=5, metric="l2", kmeans_iters=3)
     eng = AgenticMemoryEngine(cfg)
     x = corpus()
     eng.build(x)
@@ -121,7 +121,7 @@ def test_property_live_count_conserved():
     @given(n=st.integers(200, 1200), seed=st.integers(0, 1000))
     def check(n, seed):
         cfg = EngineConfig(dim=128, n_clusters=128, list_capacity=32,
-                           kmeans_iters=1, interpret=True)
+                           kmeans_iters=1)
         x = jnp.asarray(corpus(n, seed=seed))
         ids = jnp.arange(n, dtype=jnp.int32)
         state, spilled = ivf.build(jax.random.PRNGKey(seed), x, ids, cfg,

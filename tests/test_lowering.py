@@ -88,18 +88,26 @@ def test_elastic_best_grid():
     assert d * m == 48
 
 
-def test_straggler_monitor_flags_outlier():
-    import time
+def test_straggler_monitor_flags_outlier(monkeypatch):
+    import types
+    from repro.distributed import fault
     from repro.distributed.fault import StragglerMonitor
+    # a step clock the test advances, so a loaded host's sleep jitter
+    # cannot make an ordinary step look like a straggler
+    now = [0.0]
+    monkeypatch.setattr(fault, "time",
+                        types.SimpleNamespace(perf_counter=lambda: now[0]))
+
+    def step(seconds):
+        mon.start()
+        now[0] += seconds
+        return mon.stop()
+
     mon = StragglerMonitor(threshold=2.0)
     for _ in range(10):
-        mon.start()
-        time.sleep(0.002)
-        out = mon.stop()
+        out = step(0.002)
         assert not out["straggler"]
-    mon.start()
-    time.sleep(0.05)
-    out = mon.stop()
+    out = step(0.05)
     assert out["straggler"]
     assert mon.flagged == 1
 

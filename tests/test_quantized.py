@@ -98,7 +98,7 @@ def test_q8_scan_kernel_matches_ref(metric):
     got = ops.scan_scores_q8(
         jnp.asarray(q), jnp.asarray(codes), jnp.asarray(ids),
         jnp.asarray(scales), jnp.asarray(zeros), norms, metric=metric,
-        use_kernel=True, interpret=True, block_m=8, block_n=128, block_k=128)
+        use_kernel=True, block_m=8, block_n=128, block_k=128)
     want = ref.scan_scores_q8_ref(
         jnp.asarray(q), jnp.asarray(codes), jnp.asarray(ids),
         jnp.asarray(scales), jnp.asarray(zeros), norms, metric=metric)

@@ -112,6 +112,31 @@ def test_shard_local_rebuild_leaves_siblings_untouched(mesh):
     assert _live_ids(coll.snapshot()) == set(range(96, N0))
 
 
+def test_sharded_state_stays_spread_over_the_mesh(mesh):
+    """Every path that publishes a sharded state (create, build, a rebuild
+    whose residual spill is rebalanced on the host, promotion from host RAM)
+    leaves each leaf on every mesh device, never all of it on the first."""
+    def assert_spread(coll):
+        for leaf in jax.tree.leaves(coll.snapshot()):
+            assert leaf.sharding.device_set == set(mesh.devices.flat)
+
+    rng = np.random.default_rng(3)
+    # near-identical rows all land in one list per shard and overflow it
+    x = _corpus(1, seed=3) + 1e-3 * rng.standard_normal((N0, 128),
+                                                         dtype=np.float32)
+    coll = Collection("c", CFG, mesh=mesh, spill_capacity=1024)
+    assert_spread(coll)
+    coll.build(x)
+    assert_spread(coll)
+    out = coll.rebuild(shard=0)
+    assert out["rebalanced"] > 0              # spill moved via the host
+    assert_spread(coll)
+    coll.demote("warm")
+    coll.promote()
+    assert_spread(coll)
+    assert _live_ids(coll.snapshot()) == set(range(N0))
+
+
 def test_sharded_concurrent_writes_rebuild_zero_lost_rows(mesh):
     coll = _built(mesh, seed=2)
     n_ins_batches, n_del_batches = 10, 6

@@ -138,7 +138,7 @@ def test_data_pipeline_determinism_and_prefetch():
 def test_rag_prefill_smoke():
     cfg = small_cfg()
     ecfg = EngineConfig(dim=cfg.d_model, n_clusters=128, list_capacity=16,
-                        nprobe=8, k=4, kmeans_iters=2, interpret=True)
+                        nprobe=8, k=4, kmeans_iters=2)
     params = lm.init_params(jax.random.PRNGKey(0), cfg)
     rng = np.random.default_rng(0)
     mem = rng.normal(size=(500, cfg.d_model)).astype(np.float32)
