@@ -51,6 +51,7 @@ import numpy as np
 from repro.configs.base import EngineConfig
 from repro.core import index as ivf
 from repro.core import locking
+from repro.core import tracing
 
 
 class NotResident(RuntimeError):
@@ -239,11 +240,13 @@ def execute_group(collections, queries: List[np.ndarray],
         c._bump(queries=b)
     if mesh is not None:
         from repro.core import distributed as dce
-        ids, scores = dce.dist_fused_query_stacked(stacked, padded, cfg,
-                                                   mesh, k, nprobe, path)
+        fn, args = dce.dist_fused_query_stacked, (stacked, padded, cfg, mesh,
+                                                  k, nprobe, path)
     else:
-        ids, scores = fused_query(stacked, padded, cfg, k, nprobe, path)
-    ids, scores = np.asarray(ids), np.asarray(scores)
+        fn, args = fused_query, (stacked, padded, cfg, k, nprobe, path)
+    with tracing.device(fn):
+        ids, scores = fn(*args)
+        ids, scores = np.asarray(ids), np.asarray(scores)
     return [(ids[g, :b], scores[g, :b]) for g, b in enumerate(sizes)]
 
 

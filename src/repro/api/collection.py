@@ -59,6 +59,7 @@ from repro.core import index as ivf
 from repro.core import locking
 from repro.core import metrics
 from repro.core import templates
+from repro.core import tracing
 
 META_FILE = "collection.json"
 
@@ -96,8 +97,14 @@ class Collection:
         self._version = 0          # bumped on every state swap
         self._epoch = 0            # bumped on bulk build (obsoletes snapshots)
         self._next_id = 0
+        # rebuild_restarts: delta-log overflows that started a rebuild over;
+        # rebuild_exclusive: attempts that held the writer lock through the
+        # recompute (writes stalled); replayed_ops/_rows: writes replayed
+        # onto rebuilt states
         self.counters = {"queries": 0, "inserts": 0, "deletes": 0,
-                         "rebuilds": 0, "spilled": 0}
+                         "rebuilds": 0, "spilled": 0, "rebuild_restarts": 0,
+                         "rebuild_exclusive": 0, "rebuild_aborts": 0,
+                         "replayed_ops": 0, "replayed_rows": 0}
         # Per-shard maintenance state; the unsharded collection is the
         # 1-shard special case.  Shard i's entries are only ever touched by
         # ops that land on shard i, so the MaintenanceController can
@@ -382,13 +389,14 @@ class Collection:
         release and retry.  Terminates because evictions only happen on
         other tenants' admissions, which are finite between our retries.
         """
-        while True:
-            self.promote()
-            self._writer_lock.acquire()
-            with self._lock:
-                if self._residency_tier == "hot":
-                    return
-            self._writer_lock.release()
+        with tracing.span("ame.writer_wait"):
+            while True:
+                self.promote()
+                self._writer_lock.acquire()
+                with self._lock:
+                    if self._residency_tier == "hot":
+                        return
+                self._writer_lock.release()
 
     @contextlib.contextmanager
     def _hot_writer(self):
@@ -516,6 +524,13 @@ class Collection:
             for key, d in deltas.items():
                 self.counters[key] += d
                 self._probe_ops += d    # recall-probe cadence counter
+
+    def _count(self, **deltas) -> None:
+        """Bump counters of maintenance events, which the recall probe's
+        cadence (ops served) does not count."""
+        with self._lock:
+            for key, d in deltas.items():
+                self.counters[key] += d
 
     def _log_delta(self, kind: str, rows, ids) -> None:
         """Record a write for every shard with an in-flight rebuild.  Caller
@@ -774,26 +789,30 @@ class Collection:
         with self._hot_writer():
             if self.sharded:
                 from repro.core import distributed as dce
-                state, spilled_shards = dce.dist_insert(self._state, x, ids,
-                                                        self.cfg, self.mesh)
-                # sync: compute done before publish
-                per_shard = [int(v) for v in
-                             np.asarray(jax.device_get(spilled_shards))]
+                with tracing.device(dce.dist_insert):
+                    state, spilled_shards = dce.dist_insert(
+                        self._state, x, ids, self.cfg, self.mesh)
+                    # sync: compute done before publish
+                    per_shard = [int(v) for v in
+                                 np.asarray(jax.device_get(spilled_shards))]
             else:
-                state, spilled = ivf.insert_shared(self._state, x, ids,
-                                                   self.cfg)
-                per_shard = [int(spilled)]
-            spilled = sum(per_shard)
-            with self._lock:
-                for s, sp in enumerate(per_shard):
-                    self._shard_pressure[s]["spilled"] += sp
-                self._approx_live += int(x.shape[0])
-            self._swap(state, inserts=int(x.shape[0]), spilled=spilled)
-            self._log_delta("insert", x, ids)
-            # mirror into the derived HNSW graph (no-op until one exists);
-            # still under the writer lock, so graph order == state order
-            self._graph_apply("insert", np.asarray(x), np.asarray(ids))
-            self._ship("insert", x, ids)
+                with tracing.device(ivf.insert_shared):
+                    state, spilled = ivf.insert_shared(self._state, x, ids,
+                                                       self.cfg)
+                    per_shard = [int(spilled)]
+            with tracing.span("ame.publish"):
+                spilled = sum(per_shard)
+                with self._lock:
+                    for s, sp in enumerate(per_shard):
+                        self._shard_pressure[s]["spilled"] += sp
+                    self._approx_live += int(x.shape[0])
+                self._swap(state, inserts=int(x.shape[0]), spilled=spilled)
+                self._log_delta("insert", x, ids)
+                # mirror into the derived HNSW graph (no-op until one
+                # exists); still under the writer lock, so graph order ==
+                # state order
+                self._graph_apply("insert", np.asarray(x), np.asarray(ids))
+                self._ship("insert", x, ids)
         return spilled
 
     def delete(self, ids) -> int:
@@ -809,23 +828,28 @@ class Collection:
         with self._hot_writer():
             if self.sharded:
                 from repro.core import distributed as dce
-                state, hits = dce.dist_delete(self._state, ids, self.mesh)
-                # sync: compute done before publish
-                per_shard = [int(v) for v in np.asarray(jax.device_get(hits))]
+                with tracing.device(dce.dist_delete):
+                    state, hits = dce.dist_delete(self._state, ids, self.mesh)
+                    # sync: compute done before publish
+                    per_shard = [int(v)
+                                 for v in np.asarray(jax.device_get(hits))]
             else:
-                state, n_hit = ivf.delete_shared(self._state, ids)
-                per_shard = [int(n_hit)]
-            n_hit = sum(per_shard)
-            with self._lock:
-                for s, n in enumerate(per_shard):
-                    self._shard_pressure[s]["tombstones"] += n
-                self._approx_live = max(0, self._approx_live - n_hit)
-            self._swap(state, deletes=n_hit)
-            self._log_delta("delete", None, ids)
-            # graph delete is idempotent per id — absent ids are a no-op,
-            # matching the state's "ids not present contribute nothing"
-            self._graph_apply("delete", None, np.asarray(ids))
-            self._ship("delete", None, ids)
+                with tracing.device(ivf.delete_shared):
+                    state, n_hit = ivf.delete_shared(self._state, ids)
+                    per_shard = [int(n_hit)]
+            with tracing.span("ame.publish"):
+                n_hit = sum(per_shard)
+                with self._lock:
+                    for s, n in enumerate(per_shard):
+                        self._shard_pressure[s]["tombstones"] += n
+                    self._approx_live = max(0, self._approx_live - n_hit)
+                self._swap(state, deletes=n_hit)
+                self._log_delta("delete", None, ids)
+                # graph delete is idempotent per id — absent ids are a
+                # no-op, matching the state's "ids not present contribute
+                # nothing"
+                self._graph_apply("delete", None, np.asarray(ids))
+                self._ship("delete", None, ids)
         return n_hit
 
     def query(self, queries, k: Optional[int] = None,
@@ -848,16 +872,18 @@ class Collection:
         self._bump(queries=int(q.shape[0]))
         if self.sharded:
             from repro.core import distributed as dce
-            ids, scores = dce.dist_query(state, q, self.cfg, self.mesh, k)
+            fn, args = dce.dist_query, (state, q, self.cfg, self.mesh, k)
         elif path == "hnsw":
             # derived-graph path: host-side serial beam search at the
             # tuner-owned ef (the paper's pointer-chasing baseline, live)
             return self._query_graph(np.asarray(q), k)
         elif path == "full_scan":
-            ids, scores = ivf.query_full_scan(state, q, self.cfg, k)
+            fn, args = ivf.query_full_scan, (state, q, self.cfg, k)
         else:
-            ids, scores = ivf.query_probed(state, q, self.cfg, k, nprobe)
-        return np.asarray(ids), np.asarray(scores)
+            fn, args = ivf.query_probed, (state, q, self.cfg, k, nprobe)
+        with tracing.device(fn):
+            ids, scores = fn(*args)
+            return np.asarray(ids), np.asarray(scores)
 
     def rebuild(self, shard: Optional[int] = None, *,
                 max_restarts: int = 2) -> dict:
@@ -904,27 +930,35 @@ class Collection:
         return self._rebuild_shard(shard, max_restarts)
 
     def _rebuild_single(self, max_restarts: int) -> dict:
-        """Unsharded delta-replay rebuild (full re-cluster)."""
+        """Unsharded delta-replay rebuild (full re-cluster).  Each phase of
+        each attempt is an `ame.rebuild.<phase>` span."""
         t0 = time.perf_counter()
         with self._rebuild_locks[0]:
             restarts = 0
             while True:
                 exclusive = restarts >= max_restarts
-                # promote-then-acquire: a demoted collection has no device
-                # state to rebuild (and a demotion mid-rebuild bumps _epoch,
-                # aborting us at the publish step like a bulk build would)
-                self._acquire_writer_hot()
-                snap = self._state
-                epoch = self._epoch
-                if not exclusive:
-                    with self._lock:
-                        self._delta_logs[0] = []
-                        self._delta_overflow[0] = False
-                    self._writer_lock.release()
+                attempt = {"attempt": restarts, "exclusive": exclusive}
+                with tracing.span("ame.rebuild.snapshot", **attempt):
+                    # promote-then-acquire: a demoted collection has no
+                    # device state to rebuild (and a demotion mid-rebuild
+                    # bumps _epoch, aborting us at the publish step like a
+                    # bulk build would)
+                    self._acquire_writer_hot()
+                    snap = self._state
+                    epoch = self._epoch
+                    if exclusive:
+                        self._count(rebuild_exclusive=1)
+                    else:
+                        with self._lock:
+                            self._delta_logs[0] = []
+                            self._delta_overflow[0] = False
+                        self._writer_lock.release()
                 try:
-                    new, spilled = ivf.rebuild(self._split(), snap, self.cfg)
-                    jax.block_until_ready(new.lists)
-                    spilled = int(spilled)
+                    with tracing.span("ame.rebuild.recompute", **attempt):
+                        new, spilled = ivf.rebuild(self._split(), snap,
+                                                   self.cfg)
+                        jax.block_until_ready(new.lists)
+                        spilled = int(spilled)
                 except BaseException:
                     # stop logging and release cleanly; writes stay applied
                     if not exclusive:
@@ -937,7 +971,8 @@ class Collection:
                         self._writer_lock.release()
                     raise
                 if not exclusive:
-                    self._writer_lock.acquire()
+                    with tracing.span("ame.rebuild.lock_wait", **attempt):
+                        self._writer_lock.acquire()
                 try:
                     with self._lock:
                         log = self._delta_logs[0] or []
@@ -947,35 +982,45 @@ class Collection:
                     if self._epoch != epoch:
                         # a bulk build replaced the index mid-rebuild; our
                         # snapshot (and its tombstones) no longer exist
+                        self._count(rebuild_aborts=1)
                         return {"rebuild_s": time.perf_counter() - t0,
                                 "spilled": 0, "replayed": 0,
                                 "restarts": restarts, "aborted": True}
                     if overflow:
+                        self._count(rebuild_restarts=1)
                         restarts += 1
                         continue
                     replayed = sum(int(op.ids.shape[0]) for op in log)
                     tombstoned = 0
                     extra = 0
                     if log:
-                        new, extra, tombstoned = ivf.replay(new, log, self.cfg)
-                        jax.block_until_ready(new.lists)
-                    # replayed deletes leave real tombstones in the swapped
-                    # state — pressure must reflect them, not reset to zero.
-                    # Only the recompute's own leftover spill becomes the
-                    # floor (this rebuild just proved it cannot be drained);
-                    # replay spill was never tested against a re-cluster, so
-                    # it stays live pressure for the next rebuild to try.
-                    with self._lock:
-                        self._shard_pressure[0] = {"tombstones": tombstoned,
-                                                   "spilled": spilled + extra}
-                        self._spill_floors[0] = spilled
-                    spilled += extra
-                    self._swap(new, rebuilds=1)
-                    # the rebuilt store may have repacked/dropped slots the
-                    # incrementally-mirrored graph still reflects — drop the
-                    # derived graph; the next graph query rebuilds it from
-                    # the post-replay live rows
-                    self._graph_invalidate()
+                        with tracing.span("ame.rebuild.replay", ops=len(log),
+                                          rows=replayed, **attempt):
+                            new, extra, tombstoned = ivf.replay(new, log,
+                                                                self.cfg)
+                            jax.block_until_ready(new.lists)
+                    with tracing.span("ame.rebuild.publish", **attempt):
+                        # replayed deletes leave real tombstones in the
+                        # swapped state — pressure must reflect them, not
+                        # reset to zero.  Only the recompute's own leftover
+                        # spill becomes the floor (this rebuild just proved
+                        # it cannot be drained); replay spill was never
+                        # tested against a re-cluster, so it stays live
+                        # pressure for the next rebuild to try.
+                        with self._lock:
+                            self._shard_pressure[0] = {
+                                "tombstones": tombstoned,
+                                "spilled": spilled + extra}
+                            self._spill_floors[0] = spilled
+                        self._count(replayed_ops=len(log),
+                                    replayed_rows=replayed)
+                        spilled += extra
+                        self._swap(new, rebuilds=1)
+                        # the rebuilt store may have repacked/dropped slots
+                        # the incrementally-mirrored graph still reflects —
+                        # drop the derived graph; the next graph query
+                        # rebuilds it from the post-replay live rows
+                        self._graph_invalidate()
                     return {"rebuild_s": time.perf_counter() - t0,
                             "spilled": spilled, "replayed": replayed,
                             "restarts": restarts, "aborted": False}
@@ -985,12 +1030,13 @@ class Collection:
     def _rebuild_shard(self, shard: int, max_restarts: int) -> dict:
         """Shard-local delta-replay rebuild of one mesh shard.
 
-        Same protocol as `_rebuild_single` with two twists: the recompute is
-        `dist_rebuild` (compaction of shard `shard` only — siblings pass
-        through), and the publish step first *adopts* the rebuilt shard into
-        the CURRENT live state (`dist_adopt_shard`) so sibling-shard writes
-        that landed during the off-lock recompute are preserved without
-        replay — only this shard's logged ops are replayed onto it.
+        Same protocol (and spans, with `shard`) as `_rebuild_single` with
+        two twists: the recompute is `dist_rebuild` (compaction of shard
+        `shard` only — siblings pass through), and the publish step first
+        *adopts* the rebuilt shard into the CURRENT live state
+        (`dist_adopt_shard`) so sibling-shard writes that landed during the
+        off-lock recompute are preserved without replay — only this shard's
+        logged ops are replayed onto it.
         """
         from repro.core import distributed as dce
         t0 = time.perf_counter()
@@ -998,22 +1044,29 @@ class Collection:
             restarts = 0
             while True:
                 exclusive = restarts >= max_restarts
-                # promote-then-acquire: a demoted collection has no device
-                # state to rebuild (and a demotion mid-rebuild bumps _epoch,
-                # aborting us at the publish step like a bulk build would)
-                self._acquire_writer_hot()
-                snap = self._state
-                epoch = self._epoch
-                if not exclusive:
-                    with self._lock:
-                        self._delta_logs[shard] = []
-                        self._delta_overflow[shard] = False
-                    self._writer_lock.release()
+                attempt = {"attempt": restarts, "exclusive": exclusive,
+                           "shard": shard}
+                with tracing.span("ame.rebuild.snapshot", **attempt):
+                    # promote-then-acquire: a demoted collection has no
+                    # device state to rebuild (and a demotion mid-rebuild
+                    # bumps _epoch, aborting us at the publish step like a
+                    # bulk build would)
+                    self._acquire_writer_hot()
+                    snap = self._state
+                    epoch = self._epoch
+                    if exclusive:
+                        self._count(rebuild_exclusive=1)
+                    else:
+                        with self._lock:
+                            self._delta_logs[shard] = []
+                            self._delta_overflow[shard] = False
+                        self._writer_lock.release()
                 try:
-                    rebuilt, sp = dce.dist_rebuild(snap, self.cfg, self.mesh,
-                                                   shard=shard)
-                    jax.block_until_ready(rebuilt.lists)
-                    spilled = int(np.asarray(jax.device_get(sp))[shard])
+                    with tracing.span("ame.rebuild.recompute", **attempt):
+                        rebuilt, sp = dce.dist_rebuild(snap, self.cfg,
+                                                       self.mesh, shard=shard)
+                        jax.block_until_ready(rebuilt.lists)
+                        spilled = int(np.asarray(jax.device_get(sp))[shard])
                 except BaseException:
                     if not exclusive:
                         self._writer_lock.acquire()
@@ -1025,7 +1078,8 @@ class Collection:
                         self._writer_lock.release()
                     raise
                 if not exclusive:
-                    self._writer_lock.acquire()
+                    with tracing.span("ame.rebuild.lock_wait", **attempt):
+                        self._writer_lock.acquire()
                 try:
                     with self._lock:
                         log = self._delta_logs[shard] or []
@@ -1033,46 +1087,55 @@ class Collection:
                         self._delta_logs[shard] = None
                         self._delta_overflow[shard] = False
                     if self._epoch != epoch:
+                        self._count(rebuild_aborts=1)
                         return {"rebuild_s": time.perf_counter() - t0,
                                 "spilled": 0, "replayed": 0,
                                 "restarts": restarts, "aborted": True,
                                 "shard": shard}
                     if overflow:
+                        self._count(rebuild_restarts=1)
                         restarts += 1
                         continue
+                    replayed = sum(int(op.ids.shape[0]) for op in log)
                     # siblings keep their LIVE slices (concurrent writes
                     # already applied there); only this shard swaps in the
                     # rebuilt slice and replays its log
-                    merged = dce.dist_adopt_shard(self._state, rebuilt,
-                                                  shard, self.mesh)
-                    replayed = sum(int(op.ids.shape[0]) for op in log)
-                    extra = tombstoned = 0
-                    if log:
-                        merged, extra, tombstoned = dce.dist_replay(
-                            merged, log, shard, self.cfg, self.mesh)
-                    jax.block_until_ready(merged.lists)
-                    # Spill rebalance: rows this rebuild could not drain
-                    # (the shard's lists are full) move to an underfull
-                    # sibling's spill buffer, so effective capacity is not
-                    # bounded by the fullest shard.  The sibling's spill
-                    # pressure rises accordingly, which is what wires the
-                    # warm-up behind maintenance_due_shards(): its next
-                    # (auto-)rebuild drains the moved rows into its free
-                    # list slots.  Runs under the writer lock we hold.
-                    moved, moved_to = 0, None
-                    if spilled + extra > 0:
-                        merged, moved, moved_to = self._rebalance_spill_host(
-                            merged, shard)
-                    with self._lock:
-                        self._shard_pressure[shard] = {
-                            "tombstones": tombstoned,
-                            "spilled": max(spilled + extra - moved, 0)}
-                        self._spill_floors[shard] = max(spilled - moved, 0)
-                        if moved_to is not None:
-                            self._shard_pressure[moved_to]["spilled"] += moved
-                    spilled += extra
-                    bump = (shard,) if moved_to is None else (shard, moved_to)
-                    self._swap(merged, shards=bump, rebuilds=1)
+                    with tracing.span("ame.rebuild.replay", ops=len(log),
+                                      rows=replayed, **attempt):
+                        merged = dce.dist_adopt_shard(self._state, rebuilt,
+                                                      shard, self.mesh)
+                        extra = tombstoned = 0
+                        if log:
+                            merged, extra, tombstoned = dce.dist_replay(
+                                merged, log, shard, self.cfg, self.mesh)
+                        jax.block_until_ready(merged.lists)
+                    with tracing.span("ame.rebuild.publish", **attempt):
+                        # Spill rebalance: rows this rebuild could not drain
+                        # (the shard's lists are full) move to an underfull
+                        # sibling's spill buffer, so effective capacity is
+                        # not bounded by the fullest shard.  The sibling's
+                        # spill pressure rises accordingly, which is what
+                        # wires the warm-up behind maintenance_due_shards():
+                        # its next (auto-)rebuild drains the moved rows into
+                        # its free list slots.  Runs under the writer lock
+                        # we hold.
+                        moved, moved_to = 0, None
+                        if spilled + extra > 0:
+                            merged, moved, moved_to = (
+                                self._rebalance_spill_host(merged, shard))
+                        with self._lock:
+                            self._shard_pressure[shard] = {
+                                "tombstones": tombstoned,
+                                "spilled": max(spilled + extra - moved, 0)}
+                            self._spill_floors[shard] = max(spilled - moved, 0)
+                            if moved_to is not None:
+                                self._shard_pressure[moved_to]["spilled"] += moved
+                        self._count(replayed_ops=len(log),
+                                    replayed_rows=replayed)
+                        spilled += extra
+                        bump = ((shard,) if moved_to is None
+                                else (shard, moved_to))
+                        self._swap(merged, shards=bump, rebuilds=1)
                     return {"rebuild_s": time.perf_counter() - t0,
                             "spilled": spilled, "replayed": replayed,
                             "restarts": restarts, "aborted": False,

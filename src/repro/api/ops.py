@@ -16,6 +16,8 @@ import threading
 from dataclasses import dataclass, field
 from typing import Any, Optional
 
+import numpy as np
+
 OP_KINDS = ("build", "insert", "delete", "query", "rebuild",
             "promote", "demote", "probe")
 
@@ -71,6 +73,10 @@ class MemoryOp:
 
     @property
     def batch_size(self) -> int:
+        """Rows the op carries: vectors (a 1-D payload is one), or a
+        delete's ids."""
+        if self.kind == "delete":
+            return int(np.size(self.payload if self.ids is None else self.ids))
         shape = getattr(self.payload, "shape", None)
         if shape:
             return int(shape[0]) if len(shape) > 1 else 1
@@ -93,6 +99,7 @@ class OpFuture:
     `result()` re-raises the op's error in the caller's thread."""
 
     op: MemoryOp
+    req: int = 0              # request id, which the op's spans carry
     _event: threading.Event = field(default_factory=threading.Event)
     _result: Any = None
     _error: Optional[BaseException] = None

@@ -33,6 +33,7 @@ that safe under concurrent inserts/deletes.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import json
 import os
 import re
@@ -49,6 +50,7 @@ from repro.api.residency import ResidencyManager
 from repro.configs.base import EngineConfig
 from repro.core import locking
 from repro.core import templates
+from repro.core import tracing
 from repro.core.scheduler import AdmissionControl, Overloaded, Task, \
     WindowedScheduler
 
@@ -104,13 +106,16 @@ class MaintenanceController:
                     self.failed += 1
                     self.last_error = e
 
-    def _try_submit(self, key: Tuple[str, object], op: MemoryOp) -> bool:
+    def _try_submit(self, key: Tuple[str, object], op: MemoryOp,
+                    **pressure) -> bool:
         """Reserve slot `key` and submit `op` through the service.
 
         At most one in-flight op per slot; a finished-with-error slot backs
         off before re-submitting.  Safe to race with other pollers: the
         slot is reserved (value None) under the lock before the submit, so
         a slot never gets two concurrent ops.  Returns True iff submitted.
+        The submit runs in an `ame.maintenance.trigger` span that carries
+        the op's `req`, its kind and the `pressure` that triggered it.
         """
         with self._lock:
             if key in self._inflight:
@@ -128,7 +133,10 @@ class MaintenanceController:
                 return False              # failing slot: wait out backoff
             self._inflight[key] = None
         try:
-            fut = self._service.submit(op)
+            with tracing.span("ame.maintenance.trigger", kind=op.kind,
+                              **pressure) as sp:
+                fut = self._service.submit(op)
+                sp.set_metadata(req=fut.req)
         except BaseException as e:  # noqa: BLE001 — release the slot
             with self._lock:
                 self._inflight.pop(key, None)
@@ -165,8 +173,11 @@ class MaintenanceController:
                 continue                  # dropped between list and poll
             for shard in coll.maintenance_due_shards():
                 key = (name, shard if coll.sharded else None)
+                p = coll.maintenance_pressure()["shards"][shard]
                 if self._try_submit(key, MemoryOp("rebuild", name,
-                                                  shard=key[1])):
+                                                  shard=key[1]),
+                                    tombstones=p["tombstones"],
+                                    spilled=p["spilled"]):
                     with self._lock:
                         self.triggered += 1
                     n += 1
@@ -271,6 +282,8 @@ class MemoryService:
         self._maintenance_enabled = maintenance
         self._maintenance_poll_interval_s = maintenance_poll_interval_s
         self._maintenance: Optional[MaintenanceController] = None
+        # request ids for spans; next() on a count is atomic under the GIL
+        self._reqs = itertools.count(1)
 
     @property
     def residency(self) -> ResidencyManager:
@@ -346,8 +359,13 @@ class MemoryService:
     # Async op API — everything goes through the scheduler.
     # ------------------------------------------------------------------
     def submit(self, op: MemoryOp) -> OpFuture:
+        fut = OpFuture(op, req=next(self._reqs))
+        with tracing.span("ame.submit", req=fut.req, kind=op.kind,
+                          rows=op.batch_size):
+            return self._submit(op, fut)
+
+    def _submit(self, op: MemoryOp, fut: OpFuture) -> OpFuture:
         coll = self.collection(op.collection)     # missing tenant fails fast
-        fut = OpFuture(op)
         if op.batch and op.kind == "query":
             fut._on_wait = self.flush     # waiting on a parked op flushes
             with self._lock:
@@ -374,7 +392,7 @@ class MemoryService:
         nbytes = getattr(op.payload, "nbytes", 0)
         task = Task(fn=fn, kind=op.kind, backend=plan.backend,
                     priority=plan.priority, size_bytes=int(nbytes),
-                    shard=op.shard)
+                    req=fut.req)
         fut.task = self.scheduler.submit(task)
         return fut
 
@@ -518,7 +536,8 @@ class MemoryService:
         nbytes = getattr(op.payload, "nbytes", 0)
         fut.task = self.scheduler.submit(
             Task(fn=fn, kind="query", backend=plan.backend,
-                 priority=plan.priority, size_bytes=int(nbytes)))
+                 priority=plan.priority, size_bytes=int(nbytes),
+                 req=fut.req))
 
     def _submit_fused(self, ops: List[Tuple[MemoryOp, OpFuture]],
                       cfg: EngineConfig, k: int, nprobe: int,
@@ -597,7 +616,8 @@ class MemoryService:
         plan = templates.route("query", total, cfg, fused_lanes=len(order))
         nbytes = sum(int(getattr(op.payload, "nbytes", 0)) for op, _ in ops)
         task = Task(fn=fn, kind="query", backend=plan.backend,
-                    priority=plan.priority, size_bytes=nbytes)
+                    priority=plan.priority, size_bytes=nbytes,
+                    req=next(self._reqs), lanes=len(order))
         self.scheduler.submit(task)
         for fut in futs:
             fut.task = task

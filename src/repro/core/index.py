@@ -264,9 +264,12 @@ def build(key: jax.Array, x: jax.Array, ids: jax.Array, cfg: EngineConfig,
     from repro.core.kmeans import kmeans as _kmeans
 
     valid = ids >= 0
-    centroids, assign = _kmeans(key, x, valid, cfg)
-    state = empty_state(cfg, spill_capacity)._replace(centroids=centroids)
-    return _pack(state, x, ids, assign, cfg)
+    # scope names reach the ops' metadata in a profiler trace
+    with jax.named_scope("kmeans"):
+        centroids, assign = _kmeans(key, x, valid, cfg)
+    with jax.named_scope("pack"):
+        state = empty_state(cfg, spill_capacity)._replace(centroids=centroids)
+        return _pack(state, x, ids, assign, cfg)
 
 
 def _pack(state: "IVFState", x: jax.Array, ids: jax.Array,
@@ -381,11 +384,20 @@ def _insert(state: IVFState, x: jax.Array, ids: jax.Array,
 
 # `insert` donates the state buffer — updates are in place, the TPU analogue
 # of the paper's zero-copy ION shared buffers.  Donation invalidates the old
-# arrays, so it is ONLY safe when the caller is the state's sole owner;
-# `insert_shared` is the copying variant for states that concurrent readers
-# (scheduler-routed queries) may still hold a snapshot of.
-insert = functools.partial(jax.jit, static_argnames=("cfg",),
-                           donate_argnums=(0,))(_insert)
+# arrays, so it is ONLY safe when the caller is the state's sole owner: the
+# delta-log replay onto a rebuilt state, a replica's shipped batch, a
+# shard's local block.  `insert_shared` is the copying variant for states
+# that concurrent readers (scheduler-routed queries) may still hold a
+# snapshot of.  A trace names their device programs after the functions
+# jitted: `jit_replay_insert` and `jit__insert`.
+@functools.partial(jax.jit, static_argnames=("cfg",), donate_argnums=(0,))
+def replay_insert(state: IVFState, x: jax.Array, ids: jax.Array,
+                  cfg: EngineConfig) -> Tuple[IVFState, jax.Array]:
+    """Insert into a sole-owner state, donating its buffers."""
+    return _insert(state, x, ids, cfg)
+
+
+insert = replay_insert
 insert_shared = functools.partial(jax.jit, static_argnames=("cfg",))(_insert)
 
 
@@ -418,8 +430,15 @@ def _delete(state: IVFState, ids: jax.Array) -> Tuple[IVFState, jax.Array]:
     return new, n
 
 
-# donating / copying split: same rationale as insert / insert_shared above
-delete = functools.partial(jax.jit, donate_argnums=(0,))(_delete)
+# donating / copying split: same rationale, and trace names
+# (`jit_replay_delete`, `jit__delete`), as insert / insert_shared above
+@functools.partial(jax.jit, donate_argnums=(0,))
+def replay_delete(state: IVFState, ids: jax.Array) -> Tuple[IVFState, jax.Array]:
+    """Tombstone in a sole-owner state, donating its buffers."""
+    return _delete(state, ids)
+
+
+delete = replay_delete
 delete_shared = jax.jit(_delete)
 
 
@@ -443,17 +462,6 @@ class DeltaOp(NamedTuple):
     kind: str
     rows: Optional[jax.Array]
     ids: jax.Array
-
-
-def replay_insert(state: IVFState, rows: jax.Array, ids: jax.Array,
-                  cfg: EngineConfig) -> Tuple[IVFState, jax.Array]:
-    """Re-apply one logged insert to a sole-owner state (donating kernel)."""
-    return insert(state, rows, ids, cfg)
-
-
-def replay_delete(state: IVFState, ids: jax.Array) -> Tuple[IVFState, jax.Array]:
-    """Re-apply one logged delete to a sole-owner state (donating kernel)."""
-    return delete(state, ids)
 
 
 def replay(state: IVFState, log, cfg: EngineConfig) -> Tuple[IVFState, int, int]:

@@ -38,6 +38,8 @@ from typing import Any, Callable, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 
+from repro.core import tracing
+
 
 class Overloaded(RuntimeError):
     """Typed admission-control rejection: the target backend's queue is at
@@ -95,10 +97,8 @@ class Task:
     backend: str                 # latency | throughput | background
     priority: int = 0
     size_bytes: int = 0
-    # mesh shard a shard-local maintenance task targets (None = whole
-    # collection); lets stats/debugging attribute background rebuilds to
-    # the hot shard that triggered them
-    shard: Optional[int] = None
+    req: int = 0                 # request id; spans of this task carry it
+    lanes: int = 0               # collections a fused query dispatch serves
     submit_t: float = 0.0
     start_t: float = 0.0
     end_t: float = 0.0
@@ -120,12 +120,10 @@ class CompletedTask(NamedTuple):
 
     Deliberately NOT the Task itself: a Task pins its fn closure (op
     payloads, futures) and result arrays, which would keep up to `history`
-    payloads alive for nothing."""
+    payloads alive for nothing.  A task's backend and queue wait ride its
+    `ame.task` span instead."""
     kind: str
-    backend: str
     latency: float
-    queue_wait: float
-    shard: Optional[int] = None
 
 
 class WindowedScheduler:
@@ -294,36 +292,44 @@ class WindowedScheduler:
                     self._cond.wait()
                     task = self._try_pop(backend)
             task.start_t = time.perf_counter()
+            lanes = {"lanes": task.lanes} if task.lanes else {}
+            # the span runs from pop to done; `queued_us` carries the wait
+            # before the pop, which began on the submitting thread
+            with tracing.span("ame.task", req=task.req, kind=task.kind,
+                              backend=task.backend,
+                              queued_us=1e6 * task.queue_wait, **lanes):
+                self._run(task)
+
+    def _run(self, task: Task) -> None:
+        with tracing.running(task.req):
             try:
                 out = task.fn()
                 out = jax.block_until_ready(out) if out is not None else None
                 task.result = out
             except BaseException as e:   # noqa: BLE001 - reported to caller
                 task.error = e
-            task.end_t = time.perf_counter()
-            with self._cond:
-                self._inflight_bytes -= task.size_bytes
-                self._n_completed += 1
-                self.completed.append(CompletedTask(
-                    task.kind, task.backend, task.latency, task.queue_wait,
-                    task.shard))
-                agg = self._agg.setdefault(
-                    task.kind, {"n": 0, "wait_total": 0.0, "lat_total": 0.0})
-                agg["n"] += 1
-                agg["wait_total"] += task.queue_wait
-                agg["lat_total"] += task.latency
-                bex = self._backend_exec.setdefault(
-                    task.backend, {"n": 0, "total_s": 0.0})
-                bex["n"] += 1
-                bex["total_s"] += task.end_t - task.start_t
-            self._sem.release()
-            task.done.set()
-            # _outstanding is decremented only after done.set(), so a
-            # drain()er waking on 0 never observes a task whose done event
-            # (or result/error fields) has not been finalized yet
-            with self._cond:
-                self._outstanding -= 1
-                self._cond.notify_all()   # wake drain()ers + idle stealers
+        task.end_t = time.perf_counter()
+        with self._cond:
+            self._inflight_bytes -= task.size_bytes
+            self._n_completed += 1
+            self.completed.append(CompletedTask(task.kind, task.latency))
+            agg = self._agg.setdefault(
+                task.kind, {"n": 0, "wait_total": 0.0, "lat_total": 0.0})
+            agg["n"] += 1
+            agg["wait_total"] += task.queue_wait
+            agg["lat_total"] += task.latency
+            bex = self._backend_exec.setdefault(
+                task.backend, {"n": 0, "total_s": 0.0})
+            bex["n"] += 1
+            bex["total_s"] += task.end_t - task.start_t
+        self._sem.release()
+        task.done.set()
+        # _outstanding is decremented only after done.set(), so a
+        # drain()er waking on 0 never observes a task whose done event
+        # (or result/error fields) has not been finalized yet
+        with self._cond:
+            self._outstanding -= 1
+            self._cond.notify_all()   # wake drain()ers + idle stealers
 
     # ------------------------------------------------------------------
     def stats(self) -> dict:

@@ -178,6 +178,8 @@ def test_maintenance_controller_counts_shed_not_failed(monkeypatch):
         assert ctrl.stats()["shed"] == 1
 
         class _Fut:
+            req = 1                               # an OpFuture's request id
+
             def done(self):
                 return False
 
