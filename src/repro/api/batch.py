@@ -237,7 +237,7 @@ def execute_group(collections, queries: List[np.ndarray],
                 raise NotResident(c.name)
         stacked = _stack(snaps, mesh)
     for c, b in zip(collections, sizes):
-        c._bump(queries=b)
+        c._bump_queries(b, path)
     if mesh is not None:
         from repro.core import distributed as dce
         fn, args = dce.dist_fused_query_stacked, (stacked, padded, cfg, mesh,

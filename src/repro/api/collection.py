@@ -100,11 +100,13 @@ class Collection:
         # rebuild_restarts: delta-log overflows that started a rebuild over;
         # rebuild_exclusive: attempts that held the writer lock through the
         # recompute (writes stalled); replayed_ops/_rows: writes replayed
-        # onto rebuilt states
+        # onto rebuilt states; probed_inplace/_gather: probed query rows
+        # whose lists were scanned in place / gathered (`ivf.scans_in_place`)
         self.counters = {"queries": 0, "inserts": 0, "deletes": 0,
                          "rebuilds": 0, "spilled": 0, "rebuild_restarts": 0,
                          "rebuild_exclusive": 0, "rebuild_aborts": 0,
-                         "replayed_ops": 0, "replayed_rows": 0}
+                         "replayed_ops": 0, "replayed_rows": 0,
+                         "probed_inplace": 0, "probed_gather": 0}
         # Per-shard maintenance state; the unsharded collection is the
         # 1-shard special case.  Shard i's entries are only ever touched by
         # ops that land on shard i, so the MaintenanceController can
@@ -518,12 +520,17 @@ class Collection:
                 self._next_id = max(self._next_id, int(ids.max()) + 1)
         return jnp.asarray(ids)
 
-    def _bump(self, **deltas) -> None:
+    def _bump_queries(self, rows: int, path: str) -> None:
+        """Count `rows` query rows served on `path`, and on the probed path
+        whether their lists were scanned in place or gathered."""
         with self._lock:
             self._last_used = time.monotonic()
-            for key, d in deltas.items():
-                self.counters[key] += d
-                self._probe_ops += d    # recall-probe cadence counter
+            self.counters["queries"] += rows
+            self._probe_ops += rows     # recall-probe cadence counter
+            if path == "probed" and not self.sharded:
+                key = ("probed_inplace" if ivf.scans_in_place(self.cfg)
+                       else "probed_gather")
+                self.counters[key] += rows
 
     def _count(self, **deltas) -> None:
         """Bump counters of maintenance events, which the recall probe's
@@ -869,7 +876,7 @@ class Collection:
         q = jnp.atleast_2d(jnp.asarray(queries, jnp.float32))
         k, nprobe, path = self.resolve_query(q.shape[0], k, nprobe, path)
         state = self._query_state()
-        self._bump(queries=int(q.shape[0]))
+        self._bump_queries(int(q.shape[0]), path)
         if self.sharded:
             from repro.core import distributed as dce
             fn, args = dce.dist_query, (state, q, self.cfg, self.mesh, k)

@@ -12,8 +12,9 @@ arrays; every operation is a pure jittable function.  Layout (DESIGN.md §3):
                                 target list is full; drained at rebuild
 
 There is no pointer-chasing anywhere: queries, inserts, and rebuilds are all
-GEMM-shaped (the paper's core refactor), and the dense layout means gathers
-of probed lists are contiguous DMA streams, not random probes.
+GEMM-shaped (the paper's core refactor), and the dense layout means a probed
+list is a fixed slab of `lists`: the probed query's kernel DMAs the slabs it
+names straight from the state, with no gathered copy.
 """
 from __future__ import annotations
 
@@ -638,20 +639,58 @@ def query_full_scan_rows(state: IVFState, q: jax.Array, cfg: EngineConfig,
     return ids[idx], top, rows[idx]
 
 
+def scans_in_place(cfg: EngineConfig) -> bool:
+    """True when `query_probed` scans the probed lists where they lie
+    (`_query_probed_in_place`): an f32 store with the fused kernels.  The
+    int8 store, the jnp reference and the unfused ablation gather."""
+    return not cfg.quantized and cfg.use_kernel and cfg.fused_conversion
+
+
+def _query_probed_in_place(state: IVFState, q: jax.Array, cfg: EngineConfig,
+                           k: int, nprobe: int) -> Tuple[jax.Array, jax.Array]:
+    """The probed query with no copy of any row: the centroids and the
+    spill are scanned once for the whole batch (`ops.scan_rows`), the
+    probed lists in place through the probe table (`ops.scan_lists`), all
+    at their true width.  Only ids are gathered, to mask and to name the
+    candidates, which keep the gather path's order: each query's probed
+    slots, then the spill."""
+    b = q.shape[0]
+    l = state.lists.shape[1]
+    scan = functools.partial(ops.scan_rows, metric=cfg.metric,
+                             compute_dtype=cfg.compute_dtype)
+    cscores = scan(q, state.centroids)
+    _, probes = jax.lax.top_k(_order_scores(cscores, cfg.metric), nprobe)
+    list_scores = ops.scan_lists(q, state.lists, probes, metric=cfg.metric,
+                                 compute_dtype=cfg.compute_dtype)
+    scores = jnp.concatenate(
+        [list_scores.reshape(b, nprobe * l), scan(q, state.spill)], axis=1)
+    rids = jnp.concatenate(
+        [state.list_ids[probes].reshape(b, nprobe * l),
+         jnp.broadcast_to(state.spill_ids, (b, state.spill_ids.shape[0]))],
+        axis=1)
+    mask_val = float("inf") if cfg.metric == "l2" else float("-inf")
+    scores = jnp.where(rids >= 0, scores, mask_val)
+    top, idx = jax.lax.top_k(_order_scores(scores, cfg.metric), k)
+    return jnp.take_along_axis(rids, idx, axis=1), top
+
+
 @functools.partial(jax.jit, static_argnames=("cfg", "k", "nprobe"))
 def query_probed(state: IVFState, q: jax.Array, cfg: EngineConfig,
                  k: int, nprobe: int) -> Tuple[jax.Array, jax.Array]:
     """Latency template: IVF probe path for small query batches.
 
-    Centroid scores are one small GEMM; each query then gathers its nprobe
-    lists (contiguous slabs, not random probes) and runs one fused scan over
-    [nprobe*L + spill] rows.  Sequential over queries (lax.map) to bound the
-    working set — the windowed-submission idea applied inside the op.
+    Centroid scores are one small GEMM, then each query scans its nprobe
+    lists and the spill.  Where `scans_in_place(cfg)` the lists are read
+    where they lie (`_query_probed_in_place`).  Otherwise each query
+    gathers its lists into one [nprobe*L + spill] block and scans that,
+    sequentially over queries (lax.map) to bound the working set.
     """
     c, l, d = state.lists.shape
     # nprobe is static; clamp so k<=axis holds in the centroid top_k even
     # when a caller asks for more probes than there are clusters
     nprobe = max(1, min(nprobe, c))
+    if scans_in_place(cfg):
+        return _query_probed_in_place(state, q, cfg, k, nprobe)
     cvalid = jnp.arange(state.n_clusters, dtype=jnp.int32)
     cscores = ops.scan_scores(
         q, state.centroids, cvalid, _metric_norms(state.centroids, cfg.metric),

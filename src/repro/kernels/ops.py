@@ -4,6 +4,8 @@ These handle (a) padding arbitrary shapes up to kernel block multiples — the
 paper's M-dimension round-up to the tile size, (b) the kernel/ref dispatch
 driven by ``EngineConfig`` ablation flags, and (c) the un-fused baseline that
 materializes a converted copy (the "naive port" the paper argues against).
+The in-place scans of the probed query (`scan_lists`, `scan_rows`) pad
+nothing: they read each operand through its device layout (`layout_order`).
 
 Whether a kernel is compiled or interpreted follows from the platform alone
 (`interpret_kernels`): compiled on a TPU, Pallas interpret mode elsewhere.
@@ -14,6 +16,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax.experimental.layout import Layout
 
 from repro.kernels import ref as _ref
 from repro.kernels import scan_scores as _scan
@@ -69,6 +73,60 @@ def scan_scores(q, db, ids, db_norms=None, *, metric="ip", use_kernel=True,
         metric=metric, block_m=block_m, block_n=block_n, block_k=block_k,
         fused_conversion=fused_conversion, interpret=interpret_kernels())
     return out[:b, :n]
+
+
+def layout_order(shape, dtype=jnp.float32) -> tuple:
+    """Dims of an array of this shape, major to minor, as the default
+    device lays it out: a TPU may put a narrow last dim first to fill its
+    tiles.  The in-place scans read their operands through this view."""
+    dev = jax.devices()[0]
+    return tuple(Layout.from_pjrt_layout(dev.client.get_default_layout(
+        np.dtype(dtype), tuple(shape), dev)).major_to_minor)
+
+
+@functools.lru_cache(maxsize=None)
+def _lists_scan(metric: str, compute_dtype: str):
+    """`scan_lists` for one metric and operand dtype, with a vmap rule: the
+    kernel takes the mapped axis as its lane axis.  Pallas's own rule would
+    loop over the lanes, slicing a copy of each lane's store."""
+
+    def lanes(q, lists, probes, order):    # lane axis leading
+        return _scan.scan_lists(q, lists, probes, order=order, metric=metric,
+                                compute_dtype=jnp.dtype(compute_dtype),
+                                interpret=interpret_kernels())
+
+    @jax.custom_batching.custom_vmap
+    def scan(q, lists, probes):
+        order = layout_order(lists.shape, lists.dtype)
+        return lanes(q[None], lists[None], probes[None], order)[0]
+
+    @scan.def_vmap
+    def _rule(axis_size, in_batched, *args):
+        q, lists, probes = (
+            x if batched else jnp.broadcast_to(x, (axis_size,) + x.shape)
+            for x, batched in zip(args, in_batched))
+        order = layout_order(lists.shape, lists.dtype)
+        # one lane's dims as laid out, where the lane axis is the major one
+        order = (tuple(o - 1 for o in order[1:]) if order[0] == 0
+                 else _scan.ROWS)
+        return lanes(q, lists, probes, order), True
+
+    return scan
+
+
+def scan_lists(q, lists, probes, *, metric="ip", compute_dtype="bfloat16"):
+    """fp32[B, P, L]: each query against the lists its probe row names, read
+    in place at their true width (`scan_scores.scan_lists`).  Unmasked."""
+    return _lists_scan(metric, str(jnp.dtype(compute_dtype)))(q, lists, probes)
+
+
+def scan_rows(q, rows, *, metric="ip", compute_dtype="bfloat16"):
+    """fp32[B, N]: every query against every row, unpadded
+    (`scan_scores.scan_rows`).  Unmasked."""
+    return _scan.scan_rows(q, rows, order=layout_order(rows.shape, rows.dtype),
+                           metric=metric,
+                           compute_dtype=jnp.dtype(compute_dtype),
+                           interpret=interpret_kernels())
 
 
 @functools.partial(jax.jit, static_argnames=(

@@ -25,6 +25,16 @@ ONE pallas_call inside one jit program (vs. per-tile FastRPC calls at
 
 Masking: ``ids < 0`` marks empty/tombstoned IVF slots; their scores are set
 to -inf in the epilogue so downstream top-k never selects them.
+
+``scan_lists`` and ``scan_rows`` are the in-place variants the probed query
+uses: they read rows at their true width D (a block whose last dim is the
+array's own), so nothing is padded or copied before the kernel.
+``scan_lists`` takes the probe table as scalar prefetch and its BlockSpec
+index map picks ``lists[probes[b, p]]``: the pipeline DMAs the probed
+slabs (for a width-major store, each probed list's tile of 8 lists) from the
+index's own buffer.  ``scan_rows`` scores
+every query against one shared ``[N, D]`` matrix (the centroids, the spill
+buffer).  Both leave masking to the caller, which holds the ids.
 """
 from __future__ import annotations
 
@@ -252,3 +262,192 @@ def scan_scores(
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
         interpret=interpret,
     )(q, db, ids[None, :], db_norms[None, :])
+
+
+# ---------------------------------------------------------------------------
+# In-place scans at the rows' true width (the probed query)
+#
+# A TPU lays an array out to waste the least of its (8, 128) tiles, so a
+# store whose width is no multiple of 128 may not keep its rows contiguous:
+# f32[1024, 2048, 100] is laid out width-major, list slots minor.  Each scan
+# reads its operand through the view that is its physical layout (`order`,
+# the array's dims from major to minor), so XLA passes the buffer as it is.
+# ---------------------------------------------------------------------------
+
+# bytes of one row block in VMEM; the pipeline double-buffers it
+_BLOCK_BYTES = 4 * 2**20
+
+ROWS, PLANES = (0, 1, 2), (2, 0, 1)
+
+
+def _lanes(n: int) -> int:
+    return -(-n // 128) * 128
+
+
+def _block(n: int, unit_bytes: int):
+    """Units of n per block, and the kernel's compiler params: all n when
+    they fit `_BLOCK_BYTES`, else the largest multiple of 128 dividing n
+    that does, so each block's scores land lane-aligned.  Where no such
+    multiple exists (a list of 1,464 slots) the block is all n, and the
+    VMEM limit is raised to hold it twice, with its converted and squared
+    copies."""
+    fits = [b for b in range(128, n, 128)
+            if n % b == 0 and b * unit_bytes <= _BLOCK_BYTES]
+    if n * unit_bytes <= _BLOCK_BYTES or not fits:
+        block = n
+    else:
+        block = fits[-1]
+    if block * unit_bytes <= _BLOCK_BYTES:
+        return block, None
+    return block, pltpu.CompilerParams(
+        vmem_limit_bytes=4 * block * unit_bytes + _BLOCK_BYTES)
+
+
+def _scores_tile(q, x, *, by_column: bool, metric: str, compute_dtype):
+    """Scores f32[m, n] of queries f32[m, D] against n rows held as
+    f32[n, D], or f32[D, n] `by_column`: the operands converted in-register,
+    f32 accumulation; for L2 each row's squared norm in f32 minus twice the
+    product, as `scan_scores` computes with `_metric_norms`."""
+    contract = ((1,), (0,)) if by_column else ((1,), (1,))
+    s = jax.lax.dot_general(
+        q.astype(compute_dtype), x.astype(compute_dtype),
+        (contract, ((), ())), preferred_element_type=jnp.float32)
+    if metric == "l2":
+        if by_column:
+            norms = jnp.sum(x * x, axis=0, keepdims=True)
+        else:
+            # a ones row contracted with the squares lays the norms along
+            # lanes, as the scores lie; HIGHEST keeps the sum in f32
+            norms = jax.lax.dot_general(
+                jnp.ones((1, x.shape[1]), jnp.float32), x * x,
+                (contract, ((), ())), precision=jax.lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32)
+        s = norms - 2.0 * s
+    return s
+
+
+def _scan_lists_kernel(probes_ref, q_ref, lists_ref, out_ref, *,
+                       order, n_query: int, block_l: int, metric: str,
+                       compute_dtype):
+    g, b, p, j = (pl.program_id(i) for i in range(4))
+    if order == PLANES:
+        # the block holds the 8 lists of the probed list's sublane tile
+        x = lists_ref[:, probes_ref[g * n_query + b, p] % 8, :]
+    else:
+        x = lists_ref[...]
+    s = _scores_tile(q_ref[...], x, by_column=order == PLANES, metric=metric,
+                     compute_dtype=compute_dtype)
+    if block_l == out_ref.shape[1]:
+        out_ref[pl.ds(p, 1), :] = s
+    else:
+        out_ref[pl.ds(p, 1), pl.ds(pl.multiple_of(j * block_l, block_l),
+                                   block_l)] = s
+
+
+def _scan_rows_kernel(q_ref, rows_ref, out_ref, *, by_column: bool,
+                      metric: str, compute_dtype):
+    out_ref[...] = _scores_tile(q_ref[...], rows_ref[...],
+                                by_column=by_column, metric=metric,
+                                compute_dtype=compute_dtype)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "order", "metric", "compute_dtype", "interpret"))
+def scan_lists(
+    q: jax.Array,            # fp32[G, B, D]
+    lists: jax.Array,        # fp32[G, C, L, D]
+    probes: jax.Array,       # int32[G, B, P] list indices per query
+    *,
+    order=ROWS,              # one lane's lists' dims, major to minor, as
+                             # laid out (the lane axis is the major one)
+    metric: str = "ip",
+    compute_dtype=jnp.bfloat16,
+    interpret: bool = False,
+) -> jax.Array:
+    """Returns fp32[G, B, P, L]: in lane g, query b's scores against list
+    probes[g, b, p].  Lanes are the collections of a fused dispatch.
+
+    Grid (lane, query, probe, block of L); the probe table is scalar
+    prefetch, and the lists' index map picks each probed slab where it
+    lies.  Width-major lists (`PLANES`) are tiled 8 lists to a sublane
+    tile, so a step reads the probed list's tile of 8 and scores the one
+    it names.  Each query's [P, L] scores stay in VMEM across its probes
+    and are written back once.  Unmasked: slots with ids < 0 score like
+    any row.
+    """
+    g, b, d = q.shape
+    _, c, l, d2 = lists.shape
+    assert d == d2, (q.shape, lists.shape)
+    if order != PLANES or c % 8:
+        order = ROWS          # any other layout: XLA relays the store out
+    n_probe = probes.shape[2]
+    if order == ROWS:
+        bl, params = _block(l, _lanes(d) * 4)
+        spec = pl.BlockSpec(
+            (None, None, bl, d),
+            lambda h, i, t, j, pr: (h, pr[h * b + i, t], j, 0))
+    else:
+        bl, params = _block(l, d * 8 * 4)
+        spec = pl.BlockSpec(
+            (None, d, 8, bl),
+            lambda h, i, t, j, pr: (h, 0, pr[h * b + i, t] // 8, j))
+    kernel = functools.partial(_scan_lists_kernel, order=order, n_query=b,
+                               block_l=bl, metric=metric,
+                               compute_dtype=compute_dtype)
+    view = jnp.transpose(lists, (0,) + tuple(o + 1 for o in order))
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(g, b, n_probe, l // bl),
+            in_specs=[
+                pl.BlockSpec((None, None, 1, d),
+                             lambda h, i, t, j, pr: (h, i, 0, 0)),
+                spec,
+            ],
+            out_specs=pl.BlockSpec((None, None, n_probe, l),
+                                   lambda h, i, t, j, pr: (h, i, 0, 0)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((g, b, n_probe, l), jnp.float32),
+        compiler_params=params,
+        interpret=interpret,
+    )(probes.reshape(g * b, n_probe).astype(jnp.int32), q[:, :, None, :],
+      view)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "order", "metric", "compute_dtype", "interpret"))
+def scan_rows(
+    q: jax.Array,            # fp32[B, D]
+    rows: jax.Array,         # fp32[N, D]
+    *,
+    order=(0, 1),            # rows' dims, major to minor, as laid out
+    metric: str = "ip",
+    compute_dtype=jnp.bfloat16,
+    interpret: bool = False,
+) -> jax.Array:
+    """Returns fp32[B, N]: every query against every row, all queries in
+    one block so each row block is read once.  Unmasked, like
+    `scan_lists`."""
+    b, d = q.shape
+    n, d2 = rows.shape
+    assert d == d2, (q.shape, rows.shape)
+    by_column = tuple(order) == (1, 0)
+    if by_column:
+        bn, params = _block(n, -(-d // 8) * 8 * 4)
+        spec = pl.BlockSpec((d, bn), lambda j: (0, j))
+        rows = rows.T
+    else:
+        bn, params = _block(n, _lanes(d) * 4)
+        spec = pl.BlockSpec((bn, d), lambda j: (j, 0))
+    kernel = functools.partial(_scan_rows_kernel, by_column=by_column,
+                               metric=metric, compute_dtype=compute_dtype)
+    return pl.pallas_call(
+        kernel,
+        grid=(n // bn,),
+        in_specs=[pl.BlockSpec((b, d), lambda j: (0, 0)), spec],
+        out_specs=pl.BlockSpec((b, bn), lambda j: (0, j)),
+        out_shape=jax.ShapeDtypeStruct((b, n), jnp.float32),
+        compiler_params=params,
+        interpret=interpret,
+    )(q, rows)

@@ -252,3 +252,32 @@ def test_counters_consistent_under_concurrency():
     assert c["inserts"] == 20 * 32
     assert c["queries"] == 20 * 4
     svc.shutdown()
+
+
+def test_stats_count_probed_rows_by_list_scan():
+    """`svc.stats()` counts probed query rows by how their lists were
+    scanned: in place on an f32 store with the kernels (`probed_inplace`),
+    gathered on an int8 store or the jnp reference (`probed_gather`).  Full
+    scans count under neither; fused lanes count like per-op queries."""
+    small = dict(dim=128, n_clusters=128, list_capacity=16, nprobe=4, k=5,
+                 kmeans_iters=2)
+    cfgs = {"f32": EngineConfig(**small),
+            "int8": EngineConfig(**small, store_dtype="int8"),
+            "ref": EngineConfig(**small, use_kernel=False)}
+    x = _corpus(600, seed=5)
+    with MemoryService(maintenance=False) as svc:
+        for name, cfg in cfgs.items():
+            svc.create_collection(name, cfg)
+            svc.build(name, x)
+            svc.query(name, x[:3], path="probed")
+            svc.query(name, x[:2], path="full_scan")
+        svc.query_many([("f32", x[:4])], path="probed")
+        got = {name: {key: s[key] for key in
+                      ("queries", "probed_inplace", "probed_gather")}
+               for name, s in svc.stats()["collections"].items()}
+    assert got == {"f32": {"queries": 9, "probed_inplace": 7,
+                           "probed_gather": 0},
+                   "int8": {"queries": 5, "probed_inplace": 0,
+                            "probed_gather": 3},
+                   "ref": {"queries": 5, "probed_inplace": 0,
+                           "probed_gather": 3}}

@@ -15,6 +15,7 @@ Executable spec for the sharded arm of the batching/fusion layer
 
 Runs on the 2 fake CPU devices tests/conftest.py forces.
 """
+import dataclasses
 import threading
 
 import numpy as np
@@ -159,6 +160,54 @@ def test_fused_route_is_throughput_class():
     plan = templates.route("query", 4, UCFG, th, fused_lanes=3)
     assert plan.backend == "throughput"         # same rows, fused -> bulk
     assert plan.path == "probed"                # path still signature-driven
+
+
+# ---------------------------------------------------------------------------
+# Unsharded probed lanes on the in-place list scan
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("dim", [128, 100], ids=["d128", "d100"])
+def test_fused_probed_lanes_equal_per_op_in_place_scan(monkeypatch, dim):
+    """The vmapped `fused_query` over stacked states, each lane with its
+    own batch and probes, answers as per-op `query_probed` does when both
+    scan the probed lists in place (kernels on, f32 store).  The unaligned
+    width is read through the width-major view a TPU lays it out in."""
+    import jax.numpy as jnp
+
+    from repro.api import batch
+    from repro.core import index as ivf
+    from repro.kernels import ops
+
+    if dim % 128:
+        monkeypatch.setattr(ops, "layout_order",
+                            lambda shape, dtype=None: (0, 3, 1, 2)
+                            if len(shape) == 4 else
+                            (len(shape) - 1,) + tuple(range(len(shape) - 1)))
+    cfg = EngineConfig(dim=dim, n_clusters=16, list_capacity=16, nprobe=4,
+                       k=4, metric="l2" if dim % 128 else "ip",
+                       aligned=False, kmeans_iters=2)
+    build_cfg = dataclasses.replace(cfg, use_kernel=False)
+    assert ivf.scans_in_place(cfg)
+    states, queries = [], []
+    for g, b in enumerate((1, 3, 2)):
+        x = _corpus(200, seed=90 + g, dim=dim)
+        state, _ = ivf.build(jax.random.PRNGKey(g), jnp.asarray(x),
+                             jnp.arange(g * 1000, g * 1000 + 200,
+                                        dtype=jnp.int32), build_cfg,
+                             spill_capacity=32)
+        states.append(state)
+        queries.append(jnp.asarray(x[10 * g: 10 * g + b]))
+    padded = jnp.stack([jnp.pad(q, ((0, 3 - q.shape[0]), (0, 0)))
+                        for q in queries])
+    ids, scores = batch.fused_query(batch.stack_states(states), padded, cfg,
+                                    4, 4, "probed")
+    for g, (state, q) in enumerate(zip(states, queries)):
+        want_ids, want_scores = ivf.query_probed(state, q, cfg, 4, 4)
+        np.testing.assert_array_equal(np.asarray(ids[g, :q.shape[0]]),
+                                      np.asarray(want_ids))
+        np.testing.assert_allclose(np.asarray(scores[g, :q.shape[0]]),
+                                   np.asarray(want_scores), rtol=1e-6,
+                                   atol=1e-6)
 
 
 # ---------------------------------------------------------------------------

@@ -2,6 +2,8 @@
 
 Shape/dtype sweeps + hypothesis property tests, per the deliverable spec.
 """
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -261,3 +263,136 @@ def test_segsum_property_mass_conservation():
                                    rtol=1e-4, atol=1e-3)
 
     check()
+
+
+# ---------------------------------------------------------------------------
+# query_probed: the in-place list scan against the gather reference
+# ---------------------------------------------------------------------------
+
+def _width_major(shape, dtype=None):
+    """The layout a TPU gives a store whose width is no multiple of 128
+    (f32[1024, 2048, 100] is width-major, slots minor); a leading lane
+    axis stays major."""
+    n = len(shape)
+    if n == 4:
+        return (0, 3, 1, 2)
+    return (n - 1,) + tuple(range(n - 1))
+
+
+def _unit(key, shape):
+    x = jax.random.normal(key, shape)
+    return x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+
+
+def _probed_state(dim, metric, spill):
+    """16 lists; with `spill`, lists of 8 slots that 160 rows overflow,
+    else lists of 64 slots that 96 rows never fill.  Every 7th id is then
+    tombstoned, in the lists and the spill alike."""
+    cfg = EngineConfig(dim=dim, n_clusters=16,
+                       list_capacity=8 if spill else 64, nprobe=4, k=4,
+                       metric=metric, aligned=False, kmeans_iters=2)
+    n = 160 if spill else 96
+    x = _unit(jax.random.PRNGKey(dim), (n, dim))
+    ids = jnp.arange(n, dtype=jnp.int32)
+    ref_cfg = dataclasses.replace(cfg, use_kernel=False)
+    state, _ = ivf.build(jax.random.PRNGKey(3), x, ids, ref_cfg,
+                         spill_capacity=64)
+    dead = jnp.arange(0, n, 7, dtype=jnp.int32)
+    state, _ = ivf.delete_shared(state, dead)
+    return cfg, ref_cfg, state, x, set(np.asarray(dead).tolist())
+
+
+@pytest.mark.parametrize("spill", [False, True], ids=["no_spill", "spill"])
+@pytest.mark.parametrize("batch", [1, 3])
+@pytest.mark.parametrize("nprobe", [1, 5, 1000])
+@pytest.mark.parametrize("dim", [128, 100], ids=["d128", "d100"])
+@pytest.mark.parametrize("metric", ["ip", "l2"])
+def test_query_probed_in_place_matches_gather_reference(
+        monkeypatch, metric, dim, nprobe, batch, spill):
+    """The kernel path answers as the `use_kernel=False` gather path does:
+    the same ids in the same order, scores within bf16-operand tolerance;
+    tombstones never come back and live spill rows are found.  The
+    unaligned width is read through the width-major view a TPU lays it
+    out in."""
+    if dim % 128:
+        monkeypatch.setattr(ops, "layout_order", _width_major)
+    cfg, ref_cfg, state, x, dead = _probed_state(dim, metric, spill)
+    assert ivf.scans_in_place(cfg) and not ivf.scans_in_place(ref_cfg)
+    spill_ids = np.asarray(state.spill_ids)
+    live_spill = spill_ids[(spill_ids >= 0)]
+    assert (len(live_spill) > 0) == spill
+    # a live spill row (when there is one), then rows far apart, so the
+    # batch's probe sets differ
+    first = int(live_spill[0]) if spill else 1
+    q = x[jnp.asarray([first, 40, 90][:batch])]
+    got_ids, got_scores = ivf.query_probed(state, q, cfg, 4, nprobe)
+    want_ids, want_scores = ivf.query_probed(state, q, ref_cfg, 4, nprobe)
+    np.testing.assert_array_equal(np.asarray(got_ids), np.asarray(want_ids))
+    np.testing.assert_allclose(np.asarray(got_scores),
+                               np.asarray(want_scores), rtol=1e-5, atol=1e-5)
+    assert not dead & set(np.asarray(got_ids).ravel().tolist())
+    if spill:
+        assert int(got_ids[0, 0]) == first
+    if batch > 1 and nprobe < 16:
+        cscores = ref.scan_scores_ref(
+            q, state.centroids, jnp.arange(16, dtype=jnp.int32),
+            ivf._metric_norms(state.centroids, metric), metric=metric)
+        _, probes = jax.lax.top_k(ivf._order_scores(cscores, metric), nprobe)
+        assert len({tuple(p) for p in np.asarray(probes).tolist()}) > 1
+
+
+@pytest.mark.parametrize("shape", [(2, 16, 256, 100), (1, 8, 2048, 768)],
+                         ids=["one_block", "blocks"])
+@pytest.mark.parametrize("metric", ["ip", "l2"])
+@pytest.mark.parametrize("order", [(0, 1, 2), (2, 0, 1)],
+                         ids=["rows", "planes"])
+def test_scan_lists_reads_each_layout_in_place(order, metric, shape):
+    """Both views of the lists (row-major, width-major) score every lane's
+    probed lists as scanning their gathered rows does, for lanes with
+    different probe tables, whether a list fits one block or is split
+    over several (2,048 slots of 768-d)."""
+    from repro.kernels import scan_scores as sk
+    g, c, l, d = shape
+    b = 3
+    lists = _rand(jax.random.PRNGKey(5), (g, c, l, d))
+    q = _rand(jax.random.PRNGKey(6), (g, b, d))
+    probes = jax.random.permutation(
+        jax.random.PRNGKey(9), jnp.arange(g * b * 2, dtype=jnp.int32) % c
+    ).reshape(g, b, 2)
+    got = sk.scan_lists(q, lists, probes, order=order, metric=metric,
+                        interpret=True)
+    for h in range(g):
+        for i in range(b):
+            rows = lists[h][probes[h, i]].reshape(-1, d)
+            norms = ivf._metric_norms(rows, metric)
+            want = ref.scan_scores_ref(q[h, i:i + 1], rows,
+                                       jnp.zeros(rows.shape[0], jnp.int32),
+                                       norms, metric=metric)
+            np.testing.assert_allclose(np.asarray(got[h, i]).ravel(),
+                                       np.asarray(want[0]), rtol=1e-5,
+                                       atol=1e-4)
+
+
+@pytest.mark.parametrize("mapped", ["lanes", "queries"])
+def test_scan_lists_vmap_maps_onto_the_lane_axis(monkeypatch, mapped):
+    """Under vmap the mapped axis becomes the kernel's lane axis, whether
+    each element has its own store (fused lanes) or shares one, and the
+    answers are those of one call per mapped element."""
+    monkeypatch.setattr(ops, "layout_order", _width_major)
+    lists = _rand(jax.random.PRNGKey(7), (2, 16, 128, 100))
+    q = _rand(jax.random.PRNGKey(8), (2, 3, 100))
+    probes = jnp.asarray([[[0, 1], [2, 3], [4, 5]],
+                          [[6, 7], [8, 9], [1, 1]]], jnp.int32)
+
+    def scan(qq, ll, pp):
+        return ops.scan_lists(qq, ll, pp, metric="l2")
+
+    if mapped == "lanes":
+        got = jax.jit(jax.vmap(scan))(q, lists, probes)
+        want = [scan(q[h], lists[h], probes[h]) for h in range(2)]
+    else:
+        got = jax.jit(jax.vmap(scan, in_axes=(0, None, 0)))(q, lists[0],
+                                                            probes)
+        want = [scan(q[h], lists[0], probes[h]) for h in range(2)]
+    np.testing.assert_allclose(np.asarray(got), np.asarray(jnp.stack(want)),
+                               rtol=1e-6, atol=1e-5)

@@ -11,12 +11,16 @@ process at a time may load the TPU library, and every test worker imports
 this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs.base import EngineConfig
+from repro.core import index as ivf
 from repro.kernels import ops
 
 DIM, ROWS, CENTROIDS, BATCH = 768, 262_144, 1_024, 32
@@ -54,6 +58,21 @@ def compiled_kernels(monkeypatch, no_persistent_cache):
     monkeypatch.setattr(ops, "interpret_kernels", lambda: False)
 
 
+@pytest.fixture
+def chip_layouts(monkeypatch, one_chip):
+    # the in-place scans read each operand through the layout the default
+    # device gives it; ask the described chip instead, as a TPU process
+    # would ask its own
+    from jax.experimental.layout import Layout
+    dev = next(iter(one_chip.device_set))
+
+    def layout_order(shape, dtype=jnp.float32):
+        return tuple(Layout.from_pjrt_layout(dev.client.get_default_layout(
+            np.dtype(dtype), tuple(shape), dev)).major_to_minor)
+
+    monkeypatch.setattr(ops, "layout_order", layout_order)
+
+
 def _spec(sharding, shape, dtype=jnp.float32):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
@@ -89,3 +108,34 @@ def test_segsum_gemm_compiles_for_v5e(one_chip, compiled_kernels):
     _assert_kernel(ops.segsum_gemm.lower(
         _spec(one_chip, (ROWS, DIM)), _spec(one_chip, (ROWS,), jnp.int32),
         n_clusters=CENTROIDS))
+
+
+@pytest.mark.parametrize("dim,slots,metric,spill", [
+    (768, 1024, "ip", 4096), (100, 2048, "l2", 16384),
+    (1024, 592, "ip", 4096), (1024, 1464, "ip", 4096)],
+    ids=["hotpotqa768", "msturing100", "paper100k", "paper1m"])
+def test_query_probed_reads_the_store_in_place_on_v5e(
+        one_chip, compiled_kernels, chip_layouts, dim, slots, metric, spill):
+    """At the benchmark deployments' sizes, and at the list capacities of
+    `configs/ame_paper.py` (no multiple of 128; 1,464 slots of 1024-d fill
+    no 4 MiB block), a B=1 probed query compiles with the store passed to
+    the list-scan kernel as it lies: no op of the program makes an f32
+    array as large as the 32 probed lists (a copy or relayout of the
+    store, a gather or padded copy of the probed rows)."""
+    cfg = EngineConfig(dim=dim, n_clusters=CENTROIDS, list_capacity=slots,
+                       nprobe=32, k=10, metric=metric, aligned=False)
+    c, l, d = CENTROIDS, slots, dim
+    state = ivf.IVFState(
+        _spec(one_chip, (c, d)), _spec(one_chip, (c, l, d)),
+        _spec(one_chip, (c, l), jnp.int32), _spec(one_chip, (c,), jnp.int32),
+        _spec(one_chip, (spill, d)), _spec(one_chip, (spill,), jnp.int32),
+        _spec(one_chip, (), jnp.int32), _spec(one_chip, (), jnp.int32))
+    hlo = ivf.query_probed.lower(state, _spec(one_chip, (1, d)), cfg, 10,
+                                 32).compile().as_text()
+    assert "tpu_custom_call" in hlo
+    probed = 32 * l * d
+    for line in hlo.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%\S+ = f32\[([\d,]+)\]\S* ([\w-]+)\(",
+                     line)
+        if m and np.prod([int(n) for n in m.group(1).split(",")]) >= probed:
+            assert m.group(2) in ("parameter", "bitcast"), line[:160]
